@@ -114,7 +114,7 @@ def dco_screen_batch(
         m = ((k >= start) & (k < stop)).astype(jnp.float32)
         qm = q * m[None, :]
         cm = c * m[None, :]
-        dot = qm @ cm.T  # (Q, C) MXU
+        dot = jnp.matmul(qm, cm.T, precision=jax.lax.Precision.HIGHEST)
         qn = jnp.sum(qm * qm, axis=1)  # (Q,)
         cn = jnp.sum(cm * cm, axis=1)  # (C,)
         return qn[:, None] + cn[None, :] - 2.0 * dot

@@ -46,13 +46,17 @@ def merge_topk(
 
 @partial(jax.jit, static_argnames=("k",))
 def exact_knn(queries: jax.Array, corpus: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
-    """Brute-force ground truth: (Q, K) dists and ids."""
+    """Brute-force ground truth: (Q, K) dists and ids.
+
+    The dot runs at HIGHEST precision: TPU's default f32 matmul is a single
+    bf16 pass, too coarse for a reference that recall is measured against.
+    """
     q = queries.astype(jnp.float32)
     c = corpus.astype(jnp.float32)
     sq = (
         jnp.sum(q * q, axis=1)[:, None]
         + jnp.sum(c * c, axis=1)[None, :]
-        - 2.0 * q @ c.T
+        - 2.0 * jnp.matmul(q, c.T, precision=jax.lax.Precision.HIGHEST)
     )
     neg, idx = jax.lax.top_k(-sq, k)
     return jnp.sqrt(jnp.maximum(-neg, 0.0)), idx
@@ -79,7 +83,7 @@ def seed_threshold(
     sq = (
         jnp.sum(qm * qm, axis=1)[:, None]
         + jnp.sum(cm * cm, axis=1)[None, :]
-        - 2.0 * qm @ cm.T
+        - 2.0 * jnp.matmul(qm, cm.T, precision=jax.lax.Precision.HIGHEST)
     )
     est_sq = jnp.maximum(sq, 0.0) * table.scale[0]
     _, idx = jax.lax.top_k(-est_sq, k)  # (Q, K) candidate ids by estimate

@@ -47,7 +47,7 @@ class OrthogonalTransform:
 
     def apply(self, x: jax.Array) -> jax.Array:
         """Rotate vectors: x (..., D) -> W^T x (..., D)."""
-        return x @ self.basis
+        return jnp.matmul(x, self.basis, precision=jax.lax.Precision.HIGHEST)
 
     def scale(self, d: jax.Array) -> jax.Array:
         """Unbiased estimation scale sigma^2(1,D)/sigma^2(1,d) (Eq. 13).
@@ -67,7 +67,7 @@ class OrthogonalTransform:
 
 
 def _finalize(basis: jax.Array, data: jax.Array) -> OrthogonalTransform:
-    proj = data @ basis  # (N, D)
+    proj = jnp.matmul(data, basis, precision=jax.lax.Precision.HIGHEST)
     variances = jnp.mean(proj * proj, axis=0)  # zero-mean by Lemma 1 handling
     cum = jnp.cumsum(variances)
     # Guard: strictly positive cumulative variance so scale() is finite.
@@ -92,7 +92,8 @@ def fit_pca(data: jax.Array, *, center: bool = False) -> OrthogonalTransform:
     if center:
         data = data - jnp.mean(data, axis=0, keepdims=True)
     n = data.shape[0]
-    second_moment = (data.T @ data) / n  # (D, D), PSD
+    second_moment = jnp.matmul(  # (D, D), PSD
+        data.T, data, precision=jax.lax.Precision.HIGHEST) / n
     eigvals, eigvecs = jnp.linalg.eigh(second_moment)  # ascending
     order = jnp.argsort(eigvals)[::-1]
     basis = eigvecs[:, order]

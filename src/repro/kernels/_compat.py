@@ -1,45 +1,22 @@
-"""Pallas API compatibility shims (the kernel-side half of the version
-compat layer; the mesh/shard_map half lives in ``repro.launch.mesh``).
-
-Every Pallas kernel in this repo routes its compiler params and
-HBM-resident memory-space spelling through these two names, so the
-kernels lower on each jax line without per-call-site version checks.
-
-Version contracts:
+"""Pallas TPU spellings shared by every kernel in this repo.
 
 ``CompilerParams``
-    The TPU compiler-params class passed to ``pl.pallas_call``.  Accepts
-    the same keyword surface this repo uses on every supported line —
-    ``dimension_semantics=(...)`` with ``"parallel"``/``"arbitrary"``
-    entries.  Resolution order: ``pltpu.CompilerParams`` (new name) if
-    present, else ``pltpu.TPUCompilerParams`` (0.4.x name).  Construct it
-    exactly like either underlying class; it IS that class, not a wrapper.
+    The TPU compiler-params class passed to ``pl.pallas_call`` (used with
+    ``dimension_semantics=(...)`` of ``"parallel"``/``"arbitrary"``).
 
 ``ANY_MEMSPACE``
-    The "HBM-resident, let the kernel page it manually" memory space used
+    The "HBM-resident, let the kernel page it manually" memory space, used
     as ``pl.BlockSpec(memory_space=ANY_MEMSPACE)`` for the corpus streams
-    the megakernels DMA themselves.  Spellings across releases, probed in
-    order: ``pltpu.ANY`` → ``pltpu.TPUMemorySpace.ANY`` (0.4.x) →
-    ``pltpu.MemorySpace.ANY`` (newest).  Semantics are identical: the
-    operand is not BlockSpec-pipelined, the kernel sees an HBM ref it must
-    ``pltpu.make_async_copy`` from.
-
-Anything else Pallas-version-sensitive (e.g. ``PrefetchScalarGridSpec``)
-has kept one spelling across the lines this repo supports and is imported
-directly; if that changes, the shim belongs here.
+    the megakernels DMA themselves: the operand is not BlockSpec-pipelined,
+    the kernel sees an HBM ref it must ``pltpu.make_async_copy`` from.
 """
 
 from __future__ import annotations
 
+from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-if hasattr(pltpu, "ANY"):
-    ANY_MEMSPACE = pltpu.ANY
-elif hasattr(pltpu, "TPUMemorySpace"):
-    ANY_MEMSPACE = pltpu.TPUMemorySpace.ANY
-else:  # pragma: no cover - newest spelling
-    ANY_MEMSPACE = pltpu.MemorySpace.ANY
+CompilerParams = pltpu.CompilerParams
+ANY_MEMSPACE = pl.ANY
 
 __all__ = ["CompilerParams", "ANY_MEMSPACE"]

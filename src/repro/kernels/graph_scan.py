@@ -122,10 +122,11 @@ def _kernel(
     top0_sq_ref,  # (QT, EF) f32 — beam window carried in from the last wave
     top0_ids_ref,  # (QT, EF) i32
     rsq0_ref,  # (QT, 1) f32 thresholds carried in (min of seed and EF-th)
-    vis0_ref,  # (1, W) i32 — packed visited bitmap carried in
+    vis0_ref,  # (1, W) i32 — this tile's packed visited bitmap carried in
     codes_hbm,  # (N_adj, D) int8 adjacency-flat codes — HBM-resident (ANY)
     rows_hbm,  # (N_adj, D) fp adjacency-flat rows — HBM-resident (ANY)
     ids_ref,  # (1, BC) i32 neighbour ids of this step's tile, -1 padding
+    # (a (tiles, 1, BC) view of adj_ids, tile axis squeezed by the block)
     bscales_ref,  # (1, S) f32 corpus block scales
     eps_ref,  # (1, S) f32
     scale_ref,  # (1, S) f32
@@ -432,7 +433,13 @@ def graph_scan_kernel_call(
             pl.BlockSpec((block_q, ef), lambda i, s, offs, base: (i, 0)),
             pl.BlockSpec((block_q, ef), lambda i, s, offs, base: (i, 0)),
             pl.BlockSpec((block_q, 1), lambda i, s, offs, base: (i, 0)),
-            pl.BlockSpec((1, vis_words), lambda i, s, offs, base: (i, 0)),
+            # Per-tile operands carry a leading tile axis that the block
+            # squeezes, so the block's last two dims equal the array's:
+            # Mosaic refuses a (1, W) block of a (q_tiles, W) array once
+            # q_tiles > 1, and a (1, block_c) id block unless block_c is a
+            # multiple of 128.
+            pl.BlockSpec((pl.squeezed, 1, vis_words),
+                         lambda i, s, offs, base: (i, 0, 0)),
             # The adjacency streams are NOT pipelined by BlockSpec: the
             # kernel pages them manually (int8 double-buffered, fp32 slabs
             # on demand), so a fully-pruned expansion ships no fp32 bytes.
@@ -441,9 +448,9 @@ def graph_scan_kernel_call(
             # ids ride the automatic pipeline (4 B/row); -1 steps clamp to
             # tile 0, which the kernel never reads (gap steps are fully
             # predicated out via ``real``).
-            pl.BlockSpec((1, block_c),
+            pl.BlockSpec((pl.squeezed, 1, block_c),
                          lambda i, s, offs, base:
-                         (0, jnp.maximum(offs[i, s], 0))),
+                         (jnp.maximum(offs[i, s], 0), 0, 0)),
             pl.BlockSpec((1, s_count), lambda i, s, offs, base: (0, 0)),
             pl.BlockSpec((1, s_count), lambda i, s, offs, base: (0, 0)),
             pl.BlockSpec((1, s_count), lambda i, s, offs, base: (0, 0)),
@@ -453,7 +460,8 @@ def graph_scan_kernel_call(
             pl.BlockSpec((block_q, ef), lambda i, s, offs, base: (i, 0)),
             pl.BlockSpec((block_q, len(STATS_COLS)),
                          lambda i, s, offs, base: (i, 0)),
-            pl.BlockSpec((1, vis_words), lambda i, s, offs, base: (i, 0)),
+            pl.BlockSpec((pl.squeezed, 1, vis_words),
+                         lambda i, s, offs, base: (i, 0, 0)),
         ),
         scratch_shapes=[
             pltpu.VMEM((block_q, ef), jnp.float32),
@@ -472,9 +480,9 @@ def graph_scan_kernel_call(
         jax.ShapeDtypeStruct((qn, ef), jnp.float32),
         jax.ShapeDtypeStruct((qn, ef), jnp.int32),
         jax.ShapeDtypeStruct((qn, len(STATS_COLS)), jnp.float32),
-        jax.ShapeDtypeStruct((q_tiles, vis_words), jnp.int32),
+        jax.ShapeDtypeStruct((q_tiles, 1, vis_words), jnp.int32),
     )
-    return pl.pallas_call(
+    top_sq, top_ids, stats, vis = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shapes,
@@ -491,11 +499,12 @@ def graph_scan_kernel_call(
         top0_sq.astype(jnp.float32),
         top0_ids.astype(jnp.int32),
         r0_sq.reshape(-1, 1).astype(jnp.float32),
-        vis0.astype(jnp.int32),
+        vis0.astype(jnp.int32).reshape(q_tiles, 1, vis_words),
         adj_codes,
         adj_rot,  # f32 or bf16 — stage 2 upcasts per block
-        adj_ids.reshape(1, -1).astype(jnp.int32),
+        adj_ids.astype(jnp.int32).reshape(n_adj // block_c, 1, block_c),
         bscales.reshape(1, -1).astype(jnp.float32),
         eps.reshape(1, -1).astype(jnp.float32),
         scale.reshape(1, -1).astype(jnp.float32),
     )
+    return top_sq, top_ids, stats, vis.reshape(q_tiles, vis_words)

@@ -1,8 +1,11 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Handles padding to tile boundaries, table resampling to the kernel's
-block-checkpoint schedule, and the CPU fallback (interpret mode) so the same
-call-site code runs in tests/benchmarks on this host and compiles for TPU.
+block-checkpoint schedule, and the kernel mode: ``interpret=None`` resolves
+through :func:`auto_interpret` (compiled Mosaic lowering on TPU, the Pallas
+interpreter elsewhere), so the same call-site code runs in CPU tests and
+compiles for TPU.  The serving driver resolves the mode once, passes it
+explicitly and reports it.
 
 Shape/alignment contract (every fused-kernel entry point enforces these and
 fails fast with the offending value — see ``docs/ARCHITECTURE.md`` for the
@@ -44,7 +47,8 @@ from repro.quant.scalar import cum_err_sq, quantize_queries_block
 __all__ = [
     "dco_screen_kernel", "quant_screen_kernel", "ivf_scan_kernel",
     "graph_scan_kernel", "ivf_cap_tiles", "build_window_offsets",
-    "block_table", "on_tpu", "min_block_q", "fused_fetch_totals",
+    "block_table", "on_tpu", "auto_interpret", "auto_block_q", "min_block_q",
+    "fused_fetch_totals",
     "graph_vis_words", "unpack_vis", "pow2_bucket", "pad_live_rows",
     "EstimatorSpec", "UnsupportedMethodError", "kernel_spec", "EPS_DISABLED",
 ]
@@ -192,6 +196,19 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def auto_interpret() -> bool:
+    """Kernel mode for a caller that passes ``interpret=None``: compiled
+    Mosaic lowering on TPU, the Pallas interpreter on any other backend."""
+    return not on_tpu()
+
+
+def auto_block_q(interpret: bool) -> int:
+    """Query-tile rows for a kernel mode: the int8 sublane floor when the
+    kernels compile, 8 in interpret mode (tile coherence beats lane
+    occupancy there)."""
+    return 8 if interpret else min_block_q(jnp.int8)
+
+
 def block_table(table: EpsilonTable, dim: int, block_d: int):
     """Resample an EpsilonTable onto the kernel's block grid.
 
@@ -260,7 +277,7 @@ def dco_screen_kernel(
     cropped back to the caller's shapes.
     """
     if interpret is None:
-        interpret = not on_tpu()
+        interpret = auto_interpret()
     qn, dim = q_rot.shape
     n = cands_rot.shape[0]
 
@@ -324,7 +341,7 @@ def quant_screen_kernel(
     to either the distance or the error band.
     """
     if interpret is None:
-        interpret = not on_tpu()
+        interpret = auto_interpret()
     qn, dim = q_rot.shape
     n = codes.shape[0]
 
@@ -422,7 +439,7 @@ def ivf_scan_kernel(
     s1 tiles fetched]), cropped to Q.
     """
     if interpret is None:
-        interpret = not on_tpu()
+        interpret = auto_interpret()
     if not interpret and not use_ref and block_q < min_block_q(jnp.int8):
         raise ValueError(
             f"compiled lowering needs block_q >= {min_block_q(jnp.int8)} "
@@ -561,7 +578,7 @@ def graph_scan_kernel(
     turns the bitmap into the frontier-selection mask).
     """
     if interpret is None:
-        interpret = not on_tpu()
+        interpret = auto_interpret()
     if not interpret and not use_ref and block_q < min_block_q(jnp.int8):
         raise ValueError(
             f"compiled lowering needs block_q >= {min_block_q(jnp.int8)} "

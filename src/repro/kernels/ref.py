@@ -252,6 +252,50 @@ def ivf_scan_ref(
     return out
 
 
+@partial(jax.jit, static_argnames=("block_d", "slack"))
+def _graph_step_stage1(qcodes, qscales, codes, bscales, eps, scale, rsq, ids,
+                       *, block_d: int, slack: float):
+    """Stage 1 of one expansion step of ``graph_scan_ref``.  The step's ops
+    are compiled together so the host replay costs a launch per step, not
+    one per op (hundreds per step on an accelerator); the arithmetic is the
+    ``tiles`` helpers', unchanged.  Returns (active8, d8_sum, nvalid,
+    alive)."""
+    from repro.kernels.tiles import stage1_tile
+
+    valid = ids >= 0
+    validf = valid.astype(jnp.float32)
+    active8, d8 = stage1_tile(qcodes, qscales, codes, bscales, eps, scale,
+                              rsq, block_d=block_d, slack=slack)
+    d8_sum = jnp.sum(d8 * validf, axis=1, keepdims=True)
+    nvalid = jnp.broadcast_to(
+        jnp.sum(validf, axis=1, keepdims=True), d8_sum.shape)
+    alive = jnp.sum((active8 & valid).astype(jnp.int32))
+    return active8, d8_sum, nvalid, alive
+
+
+@partial(jax.jit, static_argnames=("block_d", "ef", "thresh_col", "tighten"))
+def _graph_step_stage2(q, rows, eps, scale, rsq, active8, ids, t_sq, t_ids,
+                       *, block_d: int, ef: int, thresh_col: int,
+                       tighten: bool):
+    """Stage 2 and the window merge of one expansion step of
+    ``graph_scan_ref`` (compiled per step, as ``_graph_step_stage1``).
+    Returns (t_sq, t_ids, rsq, d32_sum, npass, slabs, passed, exact_sq)."""
+    from repro.kernels.tiles import dup_mask, merge_topk_tile, stage2_tile
+
+    valid = ids >= 0
+    exact_sq, passed, d32, slabs = stage2_tile(
+        q, rows, eps, scale, rsq, active8, valid, block_d=block_d)
+    ok = passed & valid
+    d32_sum = jnp.sum(d32 * valid.astype(jnp.float32), axis=1, keepdims=True)
+    npass = jnp.sum(ok.astype(jnp.float32), axis=1, keepdims=True)
+    dup = dup_mask(ids, t_ids, k=ef)
+    new_sq = jnp.where(ok & ~dup, exact_sq, jnp.inf)
+    t_sq, t_ids = merge_topk_tile(t_sq, t_ids, new_sq, ids, k=ef)
+    if tighten:
+        rsq = jnp.minimum(rsq, t_sq[:, thresh_col:thresh_col + 1])
+    return t_sq, t_ids, rsq, d32_sum, npass, slabs, passed, exact_sq
+
+
 def graph_scan_ref(
     step_offs: jax.Array,  # (q_tiles, steps) i32 per-step tile offsets
     qcodes: jax.Array,  # (Q, D) int8
@@ -306,10 +350,6 @@ def graph_scan_ref(
     """
     import numpy as np
 
-    from repro.kernels.tiles import (
-        dup_mask, merge_topk_tile, stage1_tile, stage2_tile,
-    )
-
     qn, dim = q_rot.shape
     if thresh_col is None:
         thresh_col = ef - 1
@@ -326,6 +366,7 @@ def graph_scan_ref(
         t_ids = jnp.asarray(top0_ids[qs], jnp.int32)
         rsq = r0_sq[qs].reshape(-1, 1).astype(jnp.float32)
         st = jnp.zeros((block_q, 6), jnp.float32)
+        tile_qcodes, tile_qscales, tile_q = qcodes[qs], qscales[qs], q_rot[qs]
         last_off = None  # last issued offset — the kernel's reuse cursor
         for s in range(num_steps):
             off = int(step_offs[i, s])
@@ -337,43 +378,30 @@ def graph_scan_ref(
             vis[i, goff // 32] |= np.int32(1) << np.int32(goff % 32)
             rows = slice(off * block_c, (off + 1) * block_c)
             ids = adj_ids[rows].reshape(1, -1)
-            valid = ids >= 0
-            validf = valid.astype(jnp.float32)
             rsq_frozen = rsq
-            active8, d8 = stage1_tile(
-                qcodes[qs], qscales[qs], adj_codes[rows], bscales,
-                eps, scale, rsq_frozen, block_d=block_d, slack=slack,
-            )
-            d8_sum = jnp.sum(d8 * validf, axis=1, keepdims=True)
-            nvalid = jnp.broadcast_to(
-                jnp.sum(validf, axis=1, keepdims=True), d8_sum.shape)
+            active8, d8_sum, nvalid, alive = _graph_step_stage1(
+                tile_qcodes, tile_qscales, adj_codes[rows], bscales, eps,
+                scale, rsq_frozen, ids, block_d=block_d, slack=slack)
             zero = jnp.zeros_like(d8_sum)
             one = jnp.ones_like(d8_sum)
             s1f = one if fresh else zero
             st = st + jnp.concatenate(
                 [d8_sum, zero, nvalid, zero, zero, s1f], axis=1)
-            alive = int(jnp.sum((active8 & valid).astype(jnp.int32)))
+            alive = int(alive)
             rec = dict(tile=i, step=s, row_start=off * block_c,
                        ids=ids[0], rsq=rsq_frozen[:, 0], active8=active8,
-                       valid=valid[0], alive=alive, fetched=alive > 0,
+                       valid=ids[0] >= 0, alive=alive, fetched=alive > 0,
                        fresh=fresh, slabs=0.0, marked=goff)
             if alive > 0:
-                exact_sq, passed, d32, slabs = stage2_tile(
-                    q_rot[qs], adj_rot[rows], eps, scale, rsq_frozen,
-                    active8, valid, block_d=block_d,
-                )
-                ok = passed & valid
-                d32_sum = jnp.sum(d32 * validf, axis=1, keepdims=True)
-                npass = jnp.sum(ok.astype(jnp.float32), axis=1, keepdims=True)
+                (t_sq, t_ids, rsq, d32_sum, npass, slabs, passed,
+                 exact_sq) = _graph_step_stage2(
+                    tile_q, adj_rot[rows], eps, scale, rsq_frozen, active8,
+                    ids, t_sq, t_ids, block_d=block_d, ef=ef,
+                    thresh_col=thresh_col, tighten=tighten)
                 z = jnp.zeros_like(d32_sum)
                 slabs_col = jnp.broadcast_to(slabs, d32_sum.shape)
                 st = st + jnp.concatenate(
                     [z, d32_sum, z, npass, slabs_col, z], axis=1)
-                dup = dup_mask(ids, t_ids, k=ef)
-                new_sq = jnp.where(ok & ~dup, exact_sq, jnp.inf)
-                t_sq, t_ids = merge_topk_tile(t_sq, t_ids, new_sq, ids, k=ef)
-                if tighten:
-                    rsq = jnp.minimum(rsq, t_sq[:, thresh_col:thresh_col + 1])
                 rec.update(passed=passed, exact_sq=exact_sq,
                            slabs=float(slabs))
             else:

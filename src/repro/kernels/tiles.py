@@ -39,9 +39,12 @@ def mxu_block_sq(qb, cb):
     ``qn + cn - 2 q·cᵀ`` with f32 accumulation on the MXU and the
     ``max(·, 0)`` clamp (the decomposition can go negative in f32 where the
     direct sum of squares cannot).  Both operands must already be f32.
+    HIGHEST precision: this is the exact stage-2 distance, and TPU's
+    default f32 dot (one bf16 pass) would make it approximate.
     """
     dot = jax.lax.dot_general(
-        qb, cb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        qb, cb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     qn = jnp.sum(qb * qb, axis=1, keepdims=True)
     cn = jnp.sum(cb * cb, axis=1, keepdims=True).T

@@ -81,7 +81,8 @@ def autotune_refine_budget(scales, sample_rot, *, k: int, wave: int,
 
 def build_graph_engine(index, *, k: int, ef: int = 48, expand: int = 2,
                        block_q: int | None = None, seed_r: bool = False,
-                       with_stats: bool = False):
+                       with_stats: bool = False,
+                       interpret: bool | None = None):
     """Serving engine for the ``--index graph`` route.
 
     Wraps the batched beam-scan megakernel (``index.graph
@@ -92,17 +93,19 @@ def build_graph_engine(index, *, k: int, ef: int = 48, expand: int = 2,
     single shard_mapped jit step: this engine runs the whole corpus per
     replica and the batcher amortizes launches across requests (queries
     shard trivially across replicas).  To shard the *corpus* of the walk
-    across a mesh use ``build_sharded_graph_engine`` instead.  ``block_q``
-    defaults to the compiled-mode sublane floor on TPU and 8 elsewhere
-    (tile coherence beats lane occupancy in interpret mode).
+    across a mesh use ``build_sharded_graph_engine`` instead.
+    ``interpret=None`` takes ``ops.auto_interpret()``; ``block_q`` defaults
+    to ``ops.auto_block_q`` of that mode.
     """
     from repro.index.graph import search_graph_fused
-    from repro.kernels.ops import min_block_q, on_tpu
+    from repro.kernels.ops import auto_block_q, auto_interpret
 
     import numpy as np
 
+    if interpret is None:
+        interpret = auto_interpret()
     if block_q is None:
-        block_q = min_block_q(jnp.int8) if on_tpu() else 8
+        block_q = auto_block_q(interpret)
 
     def step(batch_np):
         # current_tracer() resolves at CALL time, so a tracer serve.py
@@ -111,7 +114,7 @@ def build_graph_engine(index, *, k: int, ef: int = 48, expand: int = 2,
                                    batch=len(batch_np)):
             d, i, st = search_graph_fused(
                 index, jnp.asarray(batch_np), k=k, ef=ef, expand=expand,
-                block_q=block_q, seed_r=seed_r)
+                block_q=block_q, seed_r=seed_r, interpret=interpret)
         if with_stats:
             return np.asarray(d), np.asarray(i), st
         return np.asarray(d), np.asarray(i)
@@ -123,7 +126,8 @@ def build_sharded_graph_engine(index, mesh, *, k: int, ef: int = 48,
                                expand: int = 2, block_q: int | None = None,
                                seed_r: bool = False, decoupled: bool = True,
                                route_mult: float = 1.0, max_waves: int = 64,
-                               with_stats: bool = False):
+                               with_stats: bool = False,
+                               interpret: bool | None = None):
     """Corpus-sharded serving engine for ``--index graph --graph-shards N``.
 
     The mesh-backed realization of ``index.graph.search_graph_sharded``:
@@ -138,9 +142,7 @@ def build_sharded_graph_engine(index, mesh, *, k: int, ef: int = 48,
     the host-simulated driver uses, so the two paths return identical
     results and either is bit-identical to the ``num_shards=1, use_ref``
     single-host beam oracle).  The host drives waves and frontier
-    selection exactly as in the single-replica engine; mesh and
-    ``shard_map`` construction route through the ``launch.mesh`` /
-    ``kernels._compat`` version shims.
+    selection exactly as in the single-replica engine.
 
     Failover: every ``step`` call consults the chaos harness
     (``runtime.chaos.current_chaos()`` — the null object when no drill is
@@ -164,7 +166,8 @@ def build_sharded_graph_engine(index, mesh, *, k: int, ef: int = 48,
         dead_shard_tombstones, merge_shard_windows, search_graph_sharded,
         shard_graph_nodes,
     )
-    from repro.kernels.ops import graph_scan_kernel, min_block_q, on_tpu
+    from repro.kernels.ops import (
+        auto_block_q, auto_interpret, graph_scan_kernel)
     from repro.runtime.chaos import current_chaos
 
     axes = tuple(mesh.axis_names)
@@ -180,8 +183,10 @@ def build_sharded_graph_engine(index, mesh, *, k: int, ef: int = 48,
     if not index.has_fused:
         raise ValueError(
             "sharded graph serving needs build_graph(..., quant='int8')")
+    if interpret is None:
+        interpret = auto_interpret()
     if block_q is None:
-        block_q = min_block_q(jnp.int8) if on_tpu() else 8
+        block_q = auto_block_q(interpret)
     thresh_col = (k - 1) if decoupled else (ef - 1)
     a_block = index.adj_block
     block_d = index.scan_block_d
@@ -201,7 +206,7 @@ def build_sharded_graph_engine(index, mesh, *, k: int, ef: int = 48,
             a_rot, a_codes, a_ids, gscales, vis,
             vis_base=base, vis_nodes=n, ef=ef, thresh_col=thresh_col,
             block_q=block_q, block_c=a_block, block_d=block_d,
-            tighten=False, interpret=not on_tpu())
+            tighten=False, interpret=interpret)
         # Cross-shard frontier exchange: windows / bitmaps / stats ride one
         # all-gather per wave (the exchange ledger prices it), merged with
         # the same arithmetic as the host-simulated driver.
@@ -292,7 +297,8 @@ def build_search_step(svc: ServiceConfig, mesh, *, two_phase: bool = True,
                       seed_waves: int = 1, quant: str | None = None,
                       refine_per_wave: int | None = None,
                       fused: bool | None = None,
-                      with_stats: bool = False):
+                      with_stats: bool = False,
+                      interpret: bool | None = None):
     """Returns search_step(corpus_rot, queries_rot, eps, scale, eps_lo)
     -> (dists, ids); with ``quant="int8"``:
     search_step(corpus_rot, corpus_q, qscales, queries_rot, eps, scale,
@@ -316,9 +322,12 @@ def build_search_step(svc: ServiceConfig, mesh, *, two_phase: bool = True,
     ``quantize_block`` and ``qscales`` carries one scale per Δd block),
     survivors re-screen through the blockwise DADE schedule in-kernel, and
     the local top-K / threshold stay in VMEM across waves.  Default
-    (None): megakernel on TPU, jnp wave scan elsewhere (the kernel runs
-    interpret mode off-TPU — correct but slow, so opt in explicitly from
-    tests).
+    (None): the megakernel where it compiles, the jnp wave scan in
+    interpret mode (correct but slow there, so tests opt in explicitly).
+
+    ``interpret`` is the megakernel's mode; None takes
+    ``ops.auto_interpret()`` (compiled on TPU).  It also fixes the query
+    tile: ``ops.auto_block_q(interpret)`` rows.
 
     ``with_stats`` (fused route only) appends a third output: a replicated
     (6,) f32 vector of the megakernel's scan counters summed over shards
@@ -326,14 +335,16 @@ def build_search_step(svc: ServiceConfig, mesh, *, two_phase: bool = True,
     driver turns columns 4-5 into the fetched-vs-skipped stage-2 byte
     report per wave.
     """
-    from repro.kernels.ops import on_tpu
+    from repro.kernels.ops import auto_block_q, auto_interpret
 
     axes = tuple(mesh.axis_names)
     k = svc.k
     wave = svc.wave
     block_d = svc.delta_d
+    if interpret is None:
+        interpret = auto_interpret()
     if fused is None:
-        fused = on_tpu()
+        fused = not interpret
     if refine_per_wave is None:
         refine_per_wave = getattr(svc, "refine_per_wave", 0) or 2 * k
     refine_per_wave = min(refine_per_wave, wave)
@@ -357,7 +368,7 @@ def build_search_step(svc: ServiceConfig, mesh, *, two_phase: bool = True,
         est0 = (
             jnp.sum(qb * qb, 1)[:, None]
             + jnp.sum(cb * cb, 1)[None, :]
-            - 2.0 * qb @ cb.T
+            - 2.0 * jnp.matmul(qb, cb.T, precision=jax.lax.Precision.HIGHEST)
         )
         _, idx = jax.lax.top_k(-est0, k)
         sample = corpus[: seed_waves * wave]
@@ -424,7 +435,8 @@ def build_search_step(svc: ServiceConfig, mesh, *, two_phase: bool = True,
                 cb = jax.lax.dynamic_slice_in_dim(rows, st * block_d, block_d, 1)
                 dot = jax.lax.dot_general(
                     qb, cb, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)
+                    preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST)
                 blk = qn_blk[:, st, None] + cn_blk[None, :, st] - 2.0 * dot
                 psum = psum + jnp.maximum(blk, 0.0)
                 est = psum * scale[st]
@@ -504,7 +516,8 @@ def build_search_step(svc: ServiceConfig, mesh, *, two_phase: bool = True,
             cf = rows_q.astype(jnp.float32) * scales[None, :]  # (W, D)
             dot = jax.lax.dot_general(
                 qf, cf, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST)
             cn = jnp.sum(cf * cf, axis=1)[None, :]
             dstq = jnp.maximum(qn + cn - 2.0 * dot, 0.0)  # (Q, W) dequant dist
             lb = jnp.maximum(jnp.sqrt(dstq) - e_band, 0.0) ** 2 * (1.0 - 1e-4)
@@ -549,7 +562,6 @@ def build_search_step(svc: ServiceConfig, mesh, *, two_phase: bool = True,
         codes: (N_local, D) int8 *block*-quantized; bscales: (S,).
         """
         from repro.kernels.ivf_scan import ivf_scan_kernel_call
-        from repro.kernels.ops import on_tpu
         from repro.quant.scalar import quantize_queries_block
 
         n_local, dim = corpus.shape
@@ -558,13 +570,14 @@ def build_search_step(svc: ServiceConfig, mesh, *, two_phase: bool = True,
         if wave % 128 or n_local % wave:
             raise ValueError("fused scan needs wave % 128 == 0 and "
                              "corpus_per_device % wave == 0")
-        block_q = 32 if on_tpu() else 8
+        block_q = auto_block_q(interpret)
         if q % block_q:
             raise ValueError(f"query_batch {q} % block_q {block_q} != 0")
-        if on_tpu() and block_d % 128:
+        if not interpret and block_d % 128:
             raise ValueError(
-                f"fused TPU serving needs delta_d % 128 == 0 (demand-paged "
-                f"stage-2 slab DMA lands lane-aligned), got {block_d}; "
+                f"compiled fused serving needs delta_d % 128 == 0 "
+                f"(demand-paged stage-2 slab DMA lands lane-aligned), got "
+                f"{block_d}; "
                 f"configure ServiceConfig(delta_d=128) or route "
                 f"fused=False")
 
@@ -589,7 +602,7 @@ def build_search_step(svc: ServiceConfig, mesh, *, two_phase: bool = True,
             codes, corpus, flat_ids,
             bscales, eps, scale, k=k, block_q=block_q, block_c=block_c,
             block_d=block_d, cap_tiles=cap_tiles,
-            interpret=not on_tpu())
+            interpret=interpret)
         top_ids = jnp.where(top_ids >= 0, base + top_ids, -1)
         top_sq, top_ids = hierarchical_topk(
             top_sq, top_ids, tuple(reversed(axes)), k)
@@ -772,7 +785,8 @@ class ContinuousGraphEngine:
                  max_waves: int = 64, num_shards: int = 1, slo=None,
                  interpret: bool | None = None, use_ref: bool = False):
         from repro.index.graph import shard_graph_nodes
-        from repro.kernels.ops import graph_vis_words, min_block_q, on_tpu
+        from repro.kernels.ops import (
+            auto_block_q, auto_interpret, graph_vis_words)
 
         if not index.has_fused:
             raise ValueError(
@@ -781,7 +795,8 @@ class ContinuousGraphEngine:
         if not 1 <= k <= ef:
             raise ValueError(f"need 1 <= k <= ef, got k={k} ef={ef}")
         if block_q is None:
-            block_q = min_block_q(jnp.int8) if on_tpu() else 8
+            block_q = auto_block_q(
+                auto_interpret() if interpret is None else interpret)
         self.index = index
         self.k = k
         self.ef = ef
@@ -1081,7 +1096,7 @@ class ContinuousIVFEngine:
                  block_q: int | None = None, block_c: int = 128,
                  probe_chunk: int = 2, seed_r: bool = True, slo=None,
                  interpret: bool | None = None, use_ref: bool = False):
-        from repro.kernels.ops import min_block_q, on_tpu
+        from repro.kernels.ops import auto_block_q, auto_interpret
 
         if not index.has_fused:
             raise ValueError(
@@ -1094,7 +1109,8 @@ class ContinuousIVFEngine:
         if probe_chunk < 1:
             raise ValueError(f"probe_chunk must be >= 1, got {probe_chunk}")
         if block_q is None:
-            block_q = min_block_q(jnp.int8) if on_tpu() else 8
+            block_q = auto_block_q(
+                auto_interpret() if interpret is None else interpret)
         self.index = index
         self.k = k
         self.n_probe = min(n_probe, index.n_clusters)

@@ -1,43 +1,16 @@
-"""Production mesh construction + jax mesh/shard_map version shims.
+"""Mesh construction and the ``shard_map`` entry point of the launch layer.
 
-This module is the compat home every launch-layer mesh/shard_map use
-should route through (ROADMAP: "new code should route mesh/shard_map
-through those helpers"; the Pallas-side shims live in
-``repro.kernels._compat``).  Functions, not module constants, so importing
-never touches jax device state.
-
-Version contracts (what each shim accepts/returns, and how it maps onto
-each jax line):
+Functions, not module constants, so importing never touches jax device
+state.
 
 ``shard_map(f, *, mesh, in_specs, out_specs, check_vma=False)``
-    Accepts any callable ``f``, a concrete ``jax.sharding.Mesh`` (or, on
-    jax >= 0.5, an ``AbstractMesh`` — tracing/lowering only; executing the
-    mapped callable still needs a concrete mesh), per-argument
-    ``PartitionSpec`` trees, and the replication-check flag under its
-    NEW name ``check_vma``.  Returns the mapped callable unchanged in
-    semantics across versions:
+    ``jax.shard_map`` in keyword form.
 
-    * jax >= 0.6: forwards to top-level ``jax.shard_map`` (which already
-      spells the flag ``check_vma``).
-    * jax 0.4.x: forwards to ``jax.experimental.shard_map.shard_map`` and
-      translates ``check_vma`` to that API's ``check_rep`` keyword.
-
-    Callers always write the new spelling; the shim owns the rename.
-    Only keyword form is supported (``mesh=``, ``in_specs=``,
-    ``out_specs=``) — the positional signatures differ across versions.
-
-``make_mesh_compat(shape, axes)``
-    Accepts a device-count shape tuple and matching axis-name tuple;
-    returns a concrete ``jax.sharding.Mesh`` over ``jax.devices()`` (jax
-    errors if the shape does not match the available device count).  On
-    jax lines that have ``jax.sharding.AxisType`` (0.5+), every axis is
-    created EXPLICITLY ``Auto`` — bit-for-bit the only behaviour 0.4.x
-    meshes have, so collectives and shard_map'd code see identical axis
-    semantics on both lines (never ``Explicit``/``Manual`` axes, which
-    0.4.x cannot express).  ``AbstractMesh`` construction is NOT wrapped
-    here: its signature changed ((shape, names) on 0.5+ vs a name→size
-    tuple on 0.4.x) and only test fixtures build one — see
-    ``tests/test_sharding.py`` for the two-spelling pattern.
+``make_mesh_compat(shape, axes, devices=None)``
+    A concrete ``jax.sharding.Mesh`` with every axis ``Auto``, over
+    ``devices`` (default: ``jax.devices()``; jax errors if the shape does not
+    match the device count).  The serving driver passes the first N
+    devices so ``--devices N`` means N on every platform.
 """
 
 from __future__ import annotations
@@ -46,29 +19,17 @@ import jax
 
 __all__ = ["make_mesh_compat", "make_production_mesh", "make_host_mesh", "shard_map"]
 
-try:  # jax >= 0.6 exports shard_map at top level with check_vma
-    from jax import shard_map as _shard_map
 
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_vma)
-except ImportError:  # 0.4.x: experimental home, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
-def make_mesh_compat(shape, axes):
-    """jax.make_mesh across jax versions (contract in module docstring):
-    ``axis_types`` (with explicit Auto axes) only exists on newer
-    releases; 0.4.x meshes are Auto-only, so Auto is forced everywhere."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+def make_mesh_compat(shape, axes, devices=None):
+    """``jax.make_mesh`` with explicit ``Auto`` axes over ``devices``."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,12 +1,20 @@
 """DADE vector-search serving driver (module CLI).
 
-    PYTHONPATH=src python -m repro.launch.serve --devices 8 --requests 10 \
+    PYTHONPATH=src python -m repro.launch.serve --devices 1 --requests 10 \
         --corpus-per-device 16384 [--method adsampling|fdscanning]
 
-Builds the same sharded ``search_step`` the 512-chip dry-run compiles,
-scaled to host devices; serves batched query requests and reports QPS +
-recall against exact ground truth.  ``--method`` swaps the DCO estimator so
-the paper's baselines are servable through the identical stack.
+Builds the same sharded ``search_step`` the 512-chip dry-run compiles over
+the first ``--devices`` devices JAX sees; serves batched query requests
+and reports QPS + recall against exact ground truth.  ``--method`` swaps
+the DCO estimator so the paper's baselines are servable through the
+identical stack.  ``main(argv)`` runs in-process and returns the report.
+
+Device and kernel mode: the first line names the platform, device kind,
+device count and kernel mode, and the report carries the same fields.  On
+TPU the Pallas kernels compile (Mosaic), with Δd=128 and 32-row query
+tiles; on any other backend they run in interpret mode, with Δd=32 and
+8-row tiles, which the report names as ``kernels=interpret``.  With
+``JAX_PLATFORMS=cpu`` the driver creates ``--devices`` host devices.
 
 Telemetry (``repro.obs``): ``--metrics-json PATH`` writes the
 schema-versioned metric snapshot (provenance + config echo + the byte
@@ -45,9 +53,11 @@ import argparse
 import os
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> dict:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--devices", type=int, default=8)
+    ap.add_argument("--devices", type=int, default=1,
+                    help="serve on the first N devices JAX sees (with "
+                         "JAX_PLATFORMS=cpu, N host devices are created)")
     ap.add_argument("--requests", type=int, default=10)
     ap.add_argument("--corpus-per-device", type=int, default=16384)
     ap.add_argument("--dim", type=int, default=96)
@@ -182,7 +192,7 @@ def main() -> None:
                          "bit-identical ids to the surviving-corpus oracle "
                          "(single-shard reference walk with the same "
                          "tombstones; exits nonzero on mismatch)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.mutate_rate > 0 and args.index != "graph":
         raise SystemExit("--mutate-rate requires --index graph (the "
@@ -199,8 +209,10 @@ def main() -> None:
         raise SystemExit("--continuous and --mutate-rate are separate "
                          "drills; run them in separate serves")
 
-    os.environ.setdefault(
-        "XLA_FLAGS", f"--xla_force_host_platform_device_count={args.devices}")
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        os.environ.setdefault(
+            "XLA_FLAGS",
+            f"--xla_force_host_platform_device_count={args.devices}")
 
     import dataclasses
     import time
@@ -212,8 +224,9 @@ def main() -> None:
     from repro.configs.dade_ivf import ServiceConfig
     from repro.core import build_estimator, exact_knn
     from repro.data.pipeline import synthetic_queries, synthetic_vectors
-    from repro.kernels.ops import block_table, kernel_spec
+    from repro.kernels.ops import auto_block_q, block_table, kernel_spec
     from repro.launch.annservice import build_search_step, search_input_specs
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_mesh_compat
     from repro.obs import (
         MetricsRegistry, Tracer, set_tracer, write_chrome_trace,
@@ -222,17 +235,37 @@ def main() -> None:
     )
     from repro.obs.trace import current_tracer
 
-    n_dev = len(jax.devices())
-    mesh = make_mesh_compat((n_dev,), ("data",))
+    enable_compile_cache()
+    visible = jax.devices()
+    if args.devices < 1 or args.devices > len(visible):
+        raise SystemExit(
+            f"--devices {args.devices}: JAX sees {len(visible)} "
+            f"{visible[0].platform} device(s)")
+    devices = visible[:args.devices]
+    n_dev = len(devices)
+    platform, device_kind = devices[0].platform, devices[0].device_kind
+    # Kernel mode is the platform's, resolved once here and passed to every
+    # engine: Mosaic-compiled on TPU, the Pallas interpreter elsewhere.  It
+    # fixes the query tile (the int8 sublane floor when compiled) and Δd:
+    # compiled demand-paged stage 2 lands lane-aligned slabs, so Δd is 128
+    # there; interpret mode keeps 32 for more checkpoints at test widths.
+    interpret = platform != "tpu"
+    kernels = "interpret" if interpret else "compiled"
+    bq = auto_block_q(interpret)
+    device_report = {"platform": platform, "device_kind": device_kind,
+                     "devices": n_dev, "kernels": kernels}
+    print(f"serve: platform={platform} device_kind={device_kind!r} "
+          f"devices={n_dev} kernels={kernels} block_q={bq}")
+    mesh = make_mesh_compat((n_dev,), ("data",), devices=devices)
     svc = ServiceConfig(
         corpus_per_device=args.corpus_per_device, dim=args.dim,
-        query_batch=args.batch, k=args.k, delta_d=32, wave=4096,
-        p_s=args.p_s, quant=args.quant, refine_per_wave=args.refine_per_wave)
+        query_batch=args.batch, k=args.k, delta_d=32 if interpret else 128,
+        wave=4096, p_s=args.p_s, quant=args.quant,
+        refine_per_wave=args.refine_per_wave)
 
     n = n_dev * svc.corpus_per_device
     corpus = synthetic_vectors(n, svc.dim, seed=0)
 
-    from repro.kernels.ops import on_tpu
     from repro.runtime.chaos import (corrupt_checkpoint_leaf, current_chaos,
                                      parse_chaos, set_chaos)
     from repro.runtime.scheduler import BatchScheduler
@@ -306,7 +339,9 @@ def main() -> None:
                    ((0, 0), (0, d_pad - svc.dim)))
 
     config_echo = {k.replace("-", "_"): v for k, v in vars(args).items()}
-    config_echo.update(devices=n_dev, corpus=n, d_pad=d_pad)
+    config_echo.update(corpus=n, d_pad=d_pad, delta_d=svc.delta_d,
+                       block_q=bq, **device_report)
+    verified: list[str] = []  # acceptance checks that ran and passed
 
     def request_recalls(pairs):
         """Mean recall@k per SERVED request vs its exact ground truth
@@ -440,8 +475,11 @@ def main() -> None:
                 f" p95={lat.percentile(95):.1f}"
                 f" p99={lat.percentile(99):.1f})")
 
-    def emit(report: dict) -> None:
-        """Write the machine-readable outputs next to the printed line."""
+    def emit(report: dict) -> dict:
+        """Write the machine-readable outputs next to the printed line and
+        return the report, tagged with the device, the kernel mode and the
+        acceptance checks that passed."""
+        report = dict(report, verified=list(verified), **device_report)
         # Tag the snapshot with the DCO method that answered this run's
         # queries (the method dimension rides in the counter NAME —
         # dco.method.<method>; the schema check cross-foots it against
@@ -462,6 +500,7 @@ def main() -> None:
                   f"({len(tracer.events)} events)")
         set_tracer(None)
         set_chaos(None)
+        return report
 
     def make_scheduler(step_fn) -> BatchScheduler:
         return BatchScheduler(
@@ -476,11 +515,12 @@ def main() -> None:
         the clock starts — gt is evaluation harness, not serving work."""
         rng = np.random.default_rng(9)
         payloads = []
+        corpus_gt = jnp.asarray(corpus)  # one host-to-device copy
         for r in range(args.requests):
             nq = int(rng.integers(svc.query_batch // 2,
                                   2 * svc.query_batch))
             q = synthetic_queries(nq, svc.dim, corpus, seed=100 + r)
-            _, gt = exact_knn(jnp.asarray(q), jnp.asarray(corpus), svc.k)
+            _, gt = exact_knn(jnp.asarray(q), corpus_gt, svc.k)
             payloads.append((prep(q), np.asarray(gt)))
         return payloads
 
@@ -497,11 +537,9 @@ def main() -> None:
         from repro.data.pipeline import drifted_vectors
         from repro.index.graph import build_graph, search_graph_fused
         from repro.index.mutable import DriftWatchdog, MutableGraph
-        from repro.kernels.ops import min_block_q
         from repro.obs import record_drift, record_mutations
         from repro.runtime.chaos import ChaosError
 
-        bq = min_block_q(jnp.int8) if on_tpu() else 8
         g_m, g_efc = 16, max(2 * args.ef, 64)
         n_mut = int(round(args.requests * args.mutate_rate))
         cap = n + 2 * n_mut + 64
@@ -626,7 +664,7 @@ def main() -> None:
         def m_step(batch_np):
             d, i, _ = st["idx"].search(
                 jnp.asarray(batch_np, jnp.float32), k=svc.k, ef=args.ef,
-                expand=args.expand, block_q=bq)
+                expand=args.expand, block_q=bq, interpret=interpret)
             return np.asarray(d), np.asarray(i)
 
         compile_ms = warmup(
@@ -693,10 +731,12 @@ def main() -> None:
                 np.float32)
             t = idx.tombstones
             dv, iv, _ = idx.search(jnp.asarray(vq), k=svc.k, ef=args.ef,
-                                   expand=args.expand, block_q=bq)
+                                   expand=args.expand, block_q=bq,
+                                   interpret=interpret)
             do, io_, _ = search_graph_fused(
                 ridx, jnp.asarray(vq), k=svc.k, ef=args.ef,
-                expand=args.expand, block_q=bq, tombstones=t, exclude=t)
+                expand=args.expand, block_q=bq, tombstones=t, exclude=t,
+                interpret=interpret)
             if not np.array_equal(np.asarray(iv), np.asarray(io_)):
                 raise SystemExit(
                     "post-churn: mutated index ids diverge from the "
@@ -706,6 +746,7 @@ def main() -> None:
                 raise SystemExit(
                     "post-churn: mutated index distances diverge from the "
                     "from-scratch rebuild oracle")
+            verified.append("churn_rebuild_oracle")
             print(f"verify-churn: mutated index ({idx.ledger.upserts} "
                   f"upserts, {idx.ledger.deletes} deletes, "
                   f"{idx.ledger.requantizes} requantizes) bit-identical to "
@@ -725,19 +766,19 @@ def main() -> None:
               f"recal={wd.recalibrations} suppressed={wd.suppressed} "
               f"stat={wd.last_stat:.3f})"
               f"{shed_note(sched)}{lat_note}")
-        emit({"qps": total_q / dt, "recall": rec,
-              "compile_ms": compile_ms, "queries": total_q,
-              "requests_submitted": sched.stats["submitted"],
-              "requests_served": sched.stats["served"],
-              "requests_shed": shed,
-              "mutations_applied": idx.ledger.applied,
-              "tombstones": n_tomb,
-              "drift_fired": wd.fired,
-              "drift_recalibrations": wd.recalibrations,
-              "wal_records": wal_records})
+        report = emit({"qps": total_q / dt, "recall": rec,
+                       "compile_ms": compile_ms, "queries": total_q,
+                       "requests_submitted": sched.stats["submitted"],
+                       "requests_served": sched.stats["served"],
+                       "requests_shed": shed,
+                       "mutations_applied": idx.ledger.applied,
+                       "tombstones": n_tomb,
+                       "drift_fired": wd.fired,
+                       "drift_recalibrations": wd.recalibrations,
+                       "wal_records": wal_records})
         if st["log"] is not None:
             st["log"].close()
-        return
+        return report
 
     if args.index == "graph":
         # Batched beam-scan route: host-built NSW graph, one megakernel
@@ -776,16 +817,16 @@ def main() -> None:
                 print(f"index-ckpt: restored graph index from "
                       f"{args.index_ckpt}")
         if gidx is None:
+            t_build = time.perf_counter()
             gidx = build_graph(corpus, estimator=est, m=16,
                                ef_construction=max(2 * args.ef, 64),
                                quant="int8")
+            print(f"index: built a {n}-node graph in "
+                  f"{time.perf_counter() - t_build:.1f} s (host clock)")
             if args.index_ckpt:
                 save_graph_index(args.index_ckpt, gidx, config=graph_cfg)
                 reg.counter("serve.ckpt.saved").add(1)
                 print(f"index-ckpt: saved graph index to {args.index_ckpt}")
-        from repro.kernels.ops import min_block_q
-
-        bq = min_block_q(jnp.int8) if on_tpu() else 8
         sharded = args.graph_shards > 1
 
         if args.continuous:
@@ -804,7 +845,8 @@ def main() -> None:
             max_live = args.max_live or svc.query_batch
             engine = ContinuousGraphEngine(
                 gidx, k=svc.k, ef=args.ef, expand=args.expand, block_q=bq,
-                num_shards=args.graph_shards, slo=parse_slo(args.slo))
+                num_shards=args.graph_shards, slo=parse_slo(args.slo),
+                interpret=interpret)
             reg.gauge("serve.continuous.max_live").set(float(max_live))
 
             # Warm-up: one solo walk pays the first kernel compile outside
@@ -827,7 +869,8 @@ def main() -> None:
                 returns (dists, ids, retired) in row order."""
                 veng = ContinuousGraphEngine(
                     gidx, k=svc.k, ef=args.ef, expand=args.expand,
-                    block_q=bq, num_shards=args.graph_shards, slo=None)
+                    block_q=bq, num_shards=args.graph_shards, slo=None,
+                    interpret=interpret)
                 hmap = {veng.admit(vq[i]): i for i in range(len(vq))}
                 out = {}
                 while veng.live_count():
@@ -868,6 +911,7 @@ def main() -> None:
                     raise SystemExit(
                         "continuous serving distances diverge from the "
                         "solo batch oracle")
+                verified.append("graph_solo_oracle")
                 print(f"verify: continuous engine (shards="
                       f"{args.graph_shards}) bit-identical to the solo "
                       f"batch oracle ({nv} interleaved queries)")
@@ -934,6 +978,7 @@ def main() -> None:
                         raise SystemExit(
                             "continuous degraded serving distances diverge "
                             "from the surviving-corpus oracle")
+                    verified.append("degraded_oracle")
                     print(f"verify-degraded: continuous admissions with "
                           f"dead shards {sorted(dead)} bit-identical to "
                           f"the surviving-corpus oracle ({nv} queries)")
@@ -966,18 +1011,22 @@ def main() -> None:
                       "requests_served": s["served"],
                       "requests_shed": shed}
             report.update(deg_report)
-            emit(report)
-            return
+            return emit(report)
 
         if sharded:
-            gmesh = make_mesh_compat((args.graph_shards,), ("shard",))
+            if args.graph_shards > n_dev:
+                raise SystemExit(
+                    f"--graph-shards {args.graph_shards} needs as many "
+                    f"devices, got --devices {n_dev}")
+            gmesh = make_mesh_compat((args.graph_shards,), ("shard",),
+                                     devices=devices[:args.graph_shards])
             engine = build_sharded_graph_engine(
                 gidx, gmesh, k=svc.k, ef=args.ef, expand=args.expand,
-                block_q=bq, with_stats=True)
+                block_q=bq, with_stats=True, interpret=interpret)
         else:
             engine = build_graph_engine(gidx, k=svc.k, ef=args.ef,
                                         expand=args.expand, block_q=bq,
-                                        with_stats=True)
+                                        with_stats=True, interpret=interpret)
 
         if args.verify_graph_oracle:
             # The acceptance check: the serving engine must return
@@ -990,6 +1039,7 @@ def main() -> None:
                 synthetic_queries(svc.query_batch, svc.dim, corpus, seed=77),
                 np.float32)
             dv, iv, _ = engine(vq)
+            t_oracle = time.perf_counter()
             if sharded:
                 do, io, _ = search_graph_sharded(
                     gidx, jnp.asarray(vq), num_shards=1, k=svc.k,
@@ -999,6 +1049,7 @@ def main() -> None:
                 do, io, _ = search_graph_beam_host(
                     gidx, jnp.asarray(vq), k=svc.k, ef=args.ef,
                     expand=args.expand, block_q=bq)
+            t_oracle = time.perf_counter() - t_oracle
             if not np.array_equal(np.asarray(iv), np.asarray(io)):
                 raise SystemExit(
                     "graph serving ids diverge from the single-host beam "
@@ -1008,9 +1059,11 @@ def main() -> None:
                 raise SystemExit(
                     "graph serving distances diverge from the single-host "
                     "beam oracle")
+            verified.append("graph_oracle")
             print(f"verify: shards={args.graph_shards} engine bit-identical "
                   f"to the single-host beam oracle "
-                  f"({svc.query_batch} queries)")
+                  f"({svc.query_batch} queries; oracle replay "
+                  f"{t_oracle:.1f} s)")
 
         g_stats = []
 
@@ -1077,6 +1130,7 @@ def main() -> None:
                     raise SystemExit(
                         "degraded serving distances diverge from the "
                         "surviving-corpus oracle")
+                verified.append("degraded_oracle")
                 print(f"verify-degraded: engine with dead shards "
                       f"{sorted(dead)} bit-identical to the "
                       f"surviving-corpus oracle ({svc.query_batch} queries)")
@@ -1118,8 +1172,7 @@ def main() -> None:
                       "requests_served": sched.stats["served"],
                       "requests_shed": shed}
             report.update(deg_report)
-            emit(report)
-            return
+            return emit(report)
         gather = (np.mean([st.gather_bytes_per_query for st in g_stats])
                   if g_stats else 0.0)
         print(f"method={args.method} index=graph corpus={n} "
@@ -1131,18 +1184,17 @@ def main() -> None:
               f"fetched_B_per_q={fetched:.0f} "
               f"host_gather_B_per_q={gather:.0f} "
               f"s2_skip_rate={skip:.3f}{shed_note(sched)}{lat_note}")
-        emit({"qps": total_q / dt, "recall": rec,
-              "compile_ms": compile_ms, "waves": float(waves),
-              "fetched_bytes_per_query": float(fetched),
-              "gather_bytes_per_query": float(gather),
-              "s2_skip_rate": float(skip), "queries": total_q,
-              "requests_submitted": sched.stats["submitted"],
-              "requests_served": sched.stats["served"],
-              "requests_shed": shed})
-        return
+        return emit({"qps": total_q / dt, "recall": rec,
+                     "compile_ms": compile_ms, "waves": float(waves),
+                     "fetched_bytes_per_query": float(fetched),
+                     "gather_bytes_per_query": float(gather),
+                     "s2_skip_rate": float(skip), "queries": total_q,
+                     "requests_submitted": sched.stats["submitted"],
+                     "requests_served": sched.stats["served"],
+                     "requests_shed": shed})
 
     quant = None if args.quant == "none" else args.quant
-    fused = on_tpu() if args.fused == "auto" else args.fused == "on"
+    fused = not interpret if args.fused == "auto" else args.fused == "on"
     refine_note = ""
     if quant == "int8":
         if fused:
@@ -1182,7 +1234,8 @@ def main() -> None:
     with_stats = quant == "int8" and fused
     _, shardings = search_input_specs(svc, mesh, quant=quant, fused=fused)
     step = jax.jit(build_search_step(svc, mesh, quant=quant, fused=fused,
-                                     with_stats=with_stats),
+                                     with_stats=with_stats,
+                                     interpret=interpret),
                    in_shardings=shardings)
     corpus_dev = jax.device_put(c_rot.astype(np.dtype(svc.dtype)), shardings[0])
     if quant == "int8":
@@ -1272,7 +1325,7 @@ def main() -> None:
           f"QPS={total_q/dt:.0f} recall@{svc.k}={rec:.3f} "
           f"compile_ms={compile_ms:.0f}"
           f"{refine_note}{fetch_note}{shed_note(sched)}{lat_note}")
-    emit(report)
+    return emit(report)
 
 
 if __name__ == "__main__":

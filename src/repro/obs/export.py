@@ -27,27 +27,26 @@ SCHEMA_VERSION = 1
 
 
 def provenance() -> dict:
-    """Best-effort run attribution: git sha, jax version, device kind,
-    ISO date.  Every field degrades to a placeholder rather than raising —
-    provenance must never be the reason a bench or serve run fails."""
+    """Run attribution: git sha, jax version, the device the run used
+    (platform, kind, count), ISO date.  The git sha degrades to
+    ``"unknown"`` outside a checkout; a failed device query raises, so no
+    snapshot is ever attributed to a device it cannot name."""
     try:
         sha = subprocess.run(
             ["git", "rev-parse", "--short", "HEAD"],
             capture_output=True, text=True, timeout=5,
         ).stdout.strip() or "unknown"
-    except Exception:
+    except (OSError, subprocess.SubprocessError):
         sha = "unknown"
-    try:
-        import jax
-        jax_version = jax.__version__
-        device_kind = jax.devices()[0].device_kind
-    except Exception:
-        jax_version = "unavailable"
-        device_kind = "unavailable"
+    import jax
+
+    devices = jax.devices()
     return {
         "git_sha": sha,
-        "jax_version": jax_version,
-        "device_kind": device_kind,
+        "jax_version": jax.__version__,
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
         "date": datetime.datetime.now(datetime.timezone.utc)
                 .strftime("%Y-%m-%dT%H:%M:%SZ"),
     }

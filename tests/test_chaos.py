@@ -392,6 +392,7 @@ _DRILL = textwrap.dedent("""
 def test_serve_chaos_drill_end_to_end():
     r = subprocess.run(
         [sys.executable, "-c", _DRILL], capture_output=True, text=True,
-        env={**os.environ, "PYTHONPATH": "src"}, cwd=".", timeout=540)
+        env={**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"},
+        cwd=".", timeout=540)
     assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr[-3000:]}"
     assert "OK chaos_drill" in r.stdout

@@ -15,7 +15,6 @@ _SCRIPT = textwrap.dedent("""
     from jax.sharding import PartitionSpec as P, NamedSharding
 
     from repro.configs.dade_ivf import ServiceConfig
-    # version-compat shims (top-level jax.shard_map / axis_types are recent)
     from repro.launch.mesh import make_mesh_compat, shard_map
     from repro.core import build_estimator, exact_knn
     from repro.data.pipeline import synthetic_vectors, synthetic_queries

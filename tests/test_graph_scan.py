@@ -77,17 +77,19 @@ def test_build_rejects_small_adj_block(aniso_corpus):
 
 # ---- kernel vs oracle parity on awkward shapes ------------------------------
 
-@pytest.mark.parametrize("qn,d,block_q,ef,steps", [
-    (12, 64, 8, 16, 5),   # Q not a tile multiple, odd step count
-    (5, 40, 4, 7, 3),     # nothing 128-aligned, tiny window
-    (16, 96, 8, 32, 8),   # D padded 96 -> 96 (3 blocks)
+@pytest.mark.parametrize("qn,d,block_q,ef,steps,block_d", [
+    (12, 64, 8, 16, 5, 8),   # Q not a tile multiple, odd step count
+    (5, 40, 4, 7, 3, 8),     # nothing 128-aligned, tiny window
+    (16, 96, 8, 32, 8, 8),   # D padded 96 -> 96 (3 blocks)
+    # the compiled serving shape: two 32-row query tiles, adj_block=32,
+    # 256-d in Δd=128 slabs, ef=48
+    (64, 256, 32, 48, 12, 128),
 ])
-def test_graph_kernel_matches_ref(qn, d, block_q, ef, steps):
+def test_graph_kernel_matches_ref(qn, d, block_q, ef, steps, block_d):
     """Kernel-vs-oracle bit parity with a carried-in (partial) beam window
     and random frontier offsets including -1 gaps and repeats."""
     rng = np.random.default_rng(qn + d)
     n = 300
-    block_d = 8
     data = (rng.standard_normal((n, d)) * np.exp(-0.05 * np.arange(d))
             ).astype(np.float32)
     g = build_graph(data, m=10, ef_construction=24, delta_d=block_d,
@@ -121,6 +123,7 @@ def test_graph_kernel_matches_ref(qn, d, block_q, ef, steps):
         g.adj_ids, g.gscales, use_ref=True, **kw)
     sq1, id1, st1, vis1 = out1
     sq2, id2, st2, vis2 = out2
+    assert g.adj_block == 32
     assert np.array_equal(np.asarray(id1), np.asarray(id2))
     np.testing.assert_allclose(np.asarray(sq1), np.asarray(sq2),
                                rtol=1e-5, atol=1e-5)

@@ -20,7 +20,8 @@ import textwrap
 
 import pytest
 
-_ENV = {**os.environ, "PYTHONPATH": "src"}
+# serve creates --devices host devices only when the CPU platform is forced
+_ENV = {**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"}
 
 _CLEAN = textwrap.dedent("""
     import json, os, subprocess, sys, tempfile

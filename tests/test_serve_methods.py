@@ -23,7 +23,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _run_serve(cwd, *args):
-    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"),
+               JAX_PLATFORMS="cpu")
     return subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", *args],
         capture_output=True, text=True, env=env, cwd=str(cwd), timeout=570)

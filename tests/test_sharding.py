@@ -13,12 +13,7 @@ from repro.distributed.sharding import (
 @pytest.fixture(scope="module")
 def mesh():
     # 1 real device: build an abstract mesh over a fake axis layout.
-    # (jax >= 0.5 takes (shape, names); 0.4.x takes a name->size tuple.)
-    try:
-        return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
-    except TypeError:
-        return jax.sharding.AbstractMesh(
-            tuple(zip(("data", "model"), (16, 16))))
+    return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
 
 
 def _rules(mesh):
