@@ -59,21 +59,6 @@ def record(name: str, **metrics):
     _records[name] = row
 
 
-def record_stage_timings(name: str, tracer, *, stages: tuple):
-    """Fold a trace capture's per-stage wall-clock into the named bench
-    row: ``stage_ms.<span>`` totals from ``obs.export.span_totals`` for
-    each requested span name.  Timings land under the non-banded
-    ``stage_ms`` key (wall-clock is machine-dependent — trajectory data,
-    not a regression gate)."""
-    from repro.obs.export import span_totals
-
-    totals = span_totals(tracer)
-    row = _records.setdefault(name, {})
-    row["stage_ms"] = {
-        s: round(totals[s]["total_ms"], 3) for s in stages if s in totals
-    }
-
-
 def write_bench_json(path: str = "BENCH_dco.json"):
     payload = {
         "fixture": {"corpus_n": CORPUS_N, "dim": DIM, "nq": NQ, "k": K},

@@ -115,16 +115,6 @@ def main():
     assert matched is not None, (
         f"fused IVF never reached host recall {r_host:.3f}")
     n_probe, r_f, st_m = matched
-    # Span-derived stage timings at the matched operating point: one
-    # traced re-run (results bit-identical — the tracer only adds fences)
-    # folds route/seed/launch wall-clock into the trajectory row.
-    from benchmarks.common import record_stage_timings
-    from repro.obs import Tracer, use_tracer
-
-    tr = Tracer(bench="fig7")
-    with use_tracer(tr):
-        search_ivf_fused(idx, jnp.asarray(queries), k=k, n_probe=n_probe,
-                         block_q=4, block_c=BLOCK_C)
     bpq_f = st_m.bytes_per_query
     fpq_f = st_m.fetched_bytes_per_query
     reduction = bpq_h / max(bpq_f, 1.0)
@@ -142,8 +132,6 @@ def main():
            s2_slabs_fetched=st_m.s2_slabs_fetched,
            nonpaged_fetched_per_query=nonpaged,
            pr2_trajectory_bytes=PR2_FUSED_BYTES_PER_QUERY)
-    record_stage_timings("fused_vs_host", tr,
-                         stages=("ivf.route", "ivf.seed", "ivf.launch"))
     assert bpq_f < bpq_h, (
         f"fused path must scan fewer bytes/query at matched recall: "
         f"{bpq_f:.0f} vs {bpq_h:.0f}")

@@ -77,10 +77,8 @@ def main():
     r_o = recall(i_o, gt)
 
     # --- the 2-shard fused walk: bit-identity + ledger conservation -----
-    # Traced run: the span capture feeds per-stage wall-clock into the
-    # trajectory row (route/launch/merge/commit per wave); tracing only
-    # adds fences, so bit-identity vs the oracle still holds below.
-    from benchmarks.common import record_stage_timings
+    # Traced run: the recording tracer adds fences at every wave stage,
+    # and bit-identity vs the oracle must still hold below.
     from repro.obs import Tracer, use_tracer
 
     tr = Tracer(bench="fig9")
@@ -115,10 +113,6 @@ def main():
            exchange_bytes_per_wave=st_s.exchange_bytes_per_wave,
            exchange_bytes_per_query=st_s.exchange_bytes_per_query,
            s2_skip_rate=st_s.s2_skip_rate)
-    record_stage_timings(
-        f"graph_sharded@s{SHARDS}", tr,
-        stages=("graph.wave", "graph.route", "graph.launch", "graph.merge",
-                "graph.host_commit"))
 
     # --- the price of shard-count invariance: frozen vs tightened waves -
     d_f, i_f, st_f = search_graph_fused(g, qj, **kw)

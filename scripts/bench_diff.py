@@ -20,8 +20,8 @@ Baseline format (JSON)::
 
 Only deterministic metrics belong here (bytes/query, recall, skip rates,
 wave counts); QPS and wall clock vary by runner and must stay out.  Rows
-also carry non-metric annotations (``provenance``, ``stage_ms`` — see
-``benchmarks/common.py``) which are never banded and are skipped here.
+also carry a non-metric annotation (``provenance`` — see
+``benchmarks/common.py``) which is never banded and is skipped here.
 Exit code 1 on any violation; each failure is ONE line naming the metric
 with its baseline value, the observed value, and the percent delta.
 """
@@ -32,7 +32,7 @@ import sys
 # Annotation keys benchmarks/common.py attaches to every row; structured
 # metadata, not metrics — never compared, and ignored if a baseline
 # accidentally lists them.
-NON_METRIC_KEYS = ("provenance", "stage_ms")
+NON_METRIC_KEYS = ("provenance",)
 
 
 def _delta(got: float, ref: float) -> str:

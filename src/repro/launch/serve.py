@@ -137,11 +137,13 @@ def main(argv: list[str] | None = None) -> dict:
                          "(repro.obs envelope: provenance, config echo, "
                          "byte-ledger counters, latency histograms) to PATH")
     ap.add_argument("--trace", default=None, metavar="PATH",
-                    help="install the span tracer and write a "
+                    help="record the span tracer and write a "
                          "Perfetto-loadable Chrome-trace JSON of the run "
                          "to PATH (per-wave stage spans with byte "
-                         "attributions; adds block_until_ready fences at "
-                         "span boundaries — leave unset for peak QPS)")
+                         "attributions; the recorded spans are fenced with "
+                         "block_until_ready, so leave unset for peak QPS). "
+                         "Without it the same spans still reach any "
+                         "running jax.profiler capture, unfenced")
     ap.add_argument("--chaos", default=None, metavar="SPEC",
                     help="arm a fault-injection drill (repro.runtime.chaos): "
                          "';'-joined kind[:key=val]* tokens, e.g. "
@@ -272,7 +274,8 @@ def main(argv: list[str] | None = None) -> dict:
 
     # Telemetry: the registry always collects (writing is opt-in); the
     # tracer is installed only under --trace so the default serving path
-    # keeps the NULL_TRACER no-ops in every instrumented loop.
+    # keeps NULL_TRACER in every instrumented loop: nothing recorded, no
+    # fences, spans only while a profiler capture runs.
     reg = MetricsRegistry()
     tracer = Tracer(tool="serve", index=args.index) if args.trace else None
     set_tracer(tracer)

@@ -1,15 +1,16 @@
 """Telemetry: metrics registry, span tracer, Chrome-trace/JSON export.
 
 Three dependency-free layers (stdlib only; jax touched lazily in
-``Tracer.fence`` and ``provenance``):
+``obs.trace`` spans and ``provenance``):
 
   * ``obs.metrics``  — counters / gauges / fixed-bucket histograms under
     stable dotted names, with mergeable snapshots and bridges from the
     engine stats families (``FusedScanStats`` etc.) to the four
     accounting-regime counters.
-  * ``obs.trace``    — explicit begin/end spans with device fencing at
-    host wave boundaries; disabled mode is a module-level null tracer so
-    instrumented code carries no conditionals.
+  * ``obs.trace``    — explicit begin/end spans at host wave boundaries,
+    written into any running profiler capture unfenced; the recording
+    tracer also keeps them (fenced) for Chrome-trace export, and the
+    default null tracer keeps instrumented code free of conditionals.
   * ``obs.export``   — Perfetto-loadable Chrome-trace JSON, the
     schema-versioned metrics envelope, and run provenance.
 

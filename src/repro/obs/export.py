@@ -96,8 +96,7 @@ def write_chrome_trace(tracer, path: str) -> dict:
 def span_totals(tracer, *, arg_keys: tuple = ()) -> dict:
     """Aggregate the trace by span name: total duration (ms), count, and
     summed numeric args for ``arg_keys`` (how the acceptance test sums the
-    per-wave byte attributions against the stats ledgers, and how
-    benchmarks derive per-stage timings from a capture)."""
+    per-wave byte attributions against the stats ledgers)."""
     totals: dict[str, dict] = {}
     for e in tracer.events:
         row = totals.setdefault(e["name"], {

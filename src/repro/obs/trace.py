@@ -1,26 +1,40 @@
 """Span tracer: explicit begin/end spans at host wave boundaries.
 
-Why spans and not a profiler: the wave loops in ``index/graph.py`` and
-``index/ivf.py`` interleave device launches with host-side routing,
+Why spans and not a sampling profiler: the wave loops in ``index/graph.py``
+and ``index/ivf.py`` interleave device launches with host-side routing,
 merging, and frontier exchange.  A sampling profiler attributes that time
 to whatever Python frame it lands in; what the latency work needs is the
 paper's own decomposition — route / stage-1 DMA / stage-2 / exchange /
-merge / host-commit — measured per wave.  So the engines open explicit
-spans at those boundaries and ``fence`` (``jax.block_until_ready``) the
-device values a span is supposed to cover; without the fence, async
-dispatch books every kernel's time to whichever span happens to
-materialise the array later.
+merge / host-commit — measured per wave.  So the engines (and the
+request scheduler) open explicit spans at those boundaries.
 
-Zero-cost-when-disabled contract: the module-level current tracer defaults
-to ``NULL_TRACER``, whose ``span`` returns one preallocated no-op context
-manager and whose ``fence`` returns its argument untouched — no
-allocation, no ``if`` in the instrumented code, no jax import.  Enabling
-tracing is swapping the module-level pointer (``set_tracer``), nothing
-else; the engines never test a flag.
+Every span reaches a running profiler capture (``jax.profiler.trace`` or
+``start_trace``) as a ``jax.profiler.TraceAnnotation``: on the profiler's
+clock, beside the device's ``XLA Ops`` line, and unfenced, so the capture
+shows the host and the device as they overlap.  "Tracing on" means "a
+capture is running"; nothing else needs switching.
 
-This module is dependency-free (jax is imported lazily inside
-``Tracer.fence`` only, so the registry/export half of obs works in
-plain-CPython contexts like the CI schema check).
+Two tracers:
+
+  * ``NULL_TRACER`` (the default): ``span`` returns a ``TraceAnnotation``
+    while a capture runs, else one preallocated no-op context manager;
+    ``fence``, ``instant`` and ``annotate`` are no-ops.  With no capture
+    the instrumented code allocates nothing and tests no flag.
+  * ``Tracer`` (``serve.py --trace``): additionally records each span and
+    instant on ``perf_counter_ns`` for the Chrome-trace export, and its
+    ``fence`` (``jax.block_until_ready``) makes those recorded edges cover
+    the device work they name — without it async dispatch books every
+    kernel's time to whichever span happens to materialise the array
+    later.  The fences apply to the export only; a profiler capture needs
+    none.
+
+Enabling the recording tracer is swapping the module-level pointer
+(``set_tracer``), nothing else; the engines never test a flag.
+
+This module is dependency-free: jax is imported on the first ``span``
+(and in ``Tracer.fence``), and without jax no capture can run, so the
+registry/export half of obs works in plain-CPython contexts like the CI
+schema check.
 """
 
 from __future__ import annotations
@@ -50,17 +64,58 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+class _NoProfiler:
+    """Stands in for ``TraceAnnotation`` where jax cannot be imported."""
+
+    @staticmethod
+    def is_enabled() -> bool:
+        return False
+
+
+_annotation_cls = None  # _Annotation (or _NoProfiler), on first use
+
+
+def _annotation_class():
+    """``TraceAnnotation`` with the span interface the engines use: its
+    ``annotate`` attaches args to the captured event (``set_metadata``),
+    as the recording tracer's attaches them to its own."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return _NoProfiler
+
+    class _Annotation(TraceAnnotation):
+        __slots__ = ()
+
+        def annotate(self, **args):
+            self.set_metadata(**args)
+
+    return _Annotation
+
+
+def _annotation(name: str, args: dict):
+    """A ``TraceAnnotation`` for ``name`` while a profiler capture runs
+    (the check costs about 50 ns), else the shared no-op span."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        _annotation_cls = _annotation_class()
+    if _annotation_cls.is_enabled():
+        return _annotation_cls(name, **args)
+    return _NULL_SPAN
+
+
 class NullTracer:
-    """Disabled tracer: every operation is a no-op returning shared
-    singletons.  ``enabled`` lets rare non-hot-path code (e.g. a bench
-    harness deciding whether to export) branch, but instrumented engine
-    code must not — it just calls through."""
+    """Default tracer: records nothing.  ``span`` reaches a running
+    profiler capture and is otherwise the shared no-op singleton; the
+    other operations are no-ops.  ``enabled`` (whether spans are recorded
+    for export) lets rare non-hot-path code branch, but instrumented
+    engine code must not — it just calls through."""
 
     __slots__ = ()
     enabled = False
 
     def span(self, name: str, **args):
-        return _NULL_SPAN
+        return _annotation(name, args)
 
     def instant(self, name: str, **args):
         pass
@@ -76,21 +131,25 @@ NULL_TRACER = NullTracer()
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "args", "_t0")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
         self.name = name
         self.args = args
         self._t0 = 0
+        self._ann = _NULL_SPAN
 
     def __enter__(self):
+        self._ann = _annotation(self.name, self.args)
+        self._ann.__enter__()
         self._tracer._stack.append(self)
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         end = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
         tr = self._tracer
         popped = tr._stack.pop()
         if popped is not self:  # pragma: no cover - misuse guard
@@ -110,6 +169,8 @@ class _Span:
 class Tracer:
     """Recording tracer.  Events accumulate as plain dicts (timestamps in
     perf_counter_ns ticks; export converts to Chrome-trace microseconds).
+    Each span also reaches a running profiler capture, as on
+    ``NullTracer``.
 
     Spans are strictly nested context managers; ``instant`` records a
     zero-duration annotation event at the current depth (used for per-wave

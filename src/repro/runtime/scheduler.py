@@ -27,8 +27,14 @@ check enforces it on every serve snapshot):
 
 Counters flow into a ``repro.obs`` registry when one is attached
 (``serve.requests.submitted/served``, ``serve.shed.*``,
-``serve.retry.attempts``); without one the same tallies live in
-``stats`` — the scheduler never requires the obs layer.
+``serve.retry.attempts``, ``serve.queue.wait_s``); without one the same
+tallies live in ``stats`` — the scheduler never requires the obs layer.
+
+``BatchScheduler.drain`` opens two ``repro.obs.trace`` spans, which reach
+any running profiler capture: ``sched.pack`` (popping one batch of rows
+and stacking them into the compiled shape) and ``sched.scatter`` (handing
+a step's results back to their requests) — the scheduler's own host work
+between engine steps.
 """
 
 from __future__ import annotations
@@ -40,6 +46,7 @@ from typing import Any, Callable
 
 import numpy as np
 
+from repro.obs.trace import current_tracer
 from repro.runtime.chaos import current_chaos
 
 __all__ = ["BatchScheduler", "ContinuousScheduler", "Request"]
@@ -53,6 +60,9 @@ _METRIC_NAMES = {
     "shed_deadline": "serve.shed.deadline",
     "shed_error": "serve.shed.error",
     "retries": "serve.retry.attempts",
+    # Seconds query rows waited from enqueue to their batch's dispatch,
+    # summed over the rows ``rows`` counts (BatchScheduler only).
+    "wait_s": "serve.queue.wait_s",
     # Continuous-batching admission ledger (ContinuousScheduler only):
     # per-QUERY counts, closed by construction —
     # admitted == retired + admission_shed — next to the per-REQUEST
@@ -100,6 +110,11 @@ class BatchScheduler:
       retry_backoff_s: first-retry backoff (doubles per attempt).
       registry: optional ``repro.obs.MetricsRegistry`` — request/shed/retry
         counters land under their ``serve.*`` names.
+
+    ``stats["wait_s"]`` sums, over every row of every batch that returned
+    (the rows ``stats["rows"]`` counts), the time from the row's request
+    being enqueued to its batch starting ``_dispatch``; the mean queue
+    wait is ``wait_s / rows``.
     """
 
     def __init__(self, step_fn: Callable, batch_size: int,
@@ -116,10 +131,11 @@ class BatchScheduler:
         self._queue: deque[tuple[Request, int]] = deque()  # (req, row offset)
         self._next_rid = 0
         self.stats = {"batches": 0, "padded_rows": 0, "rows": 0,
-                      "submitted": 0, "served": 0, "shed_queue": 0,
-                      "shed_deadline": 0, "shed_error": 0, "retries": 0}
+                      "wait_s": 0.0, "submitted": 0, "served": 0,
+                      "shed_queue": 0, "shed_deadline": 0, "shed_error": 0,
+                      "retries": 0}
 
-    def _count(self, key: str, delta: int = 1) -> None:
+    def _count(self, key: str, delta: float = 1) -> None:
         self.stats[key] += delta
         if self.registry is not None:
             self.registry.counter(_METRIC_NAMES[key]).add(delta)
@@ -186,22 +202,26 @@ class BatchScheduler:
         partial batch remains.  Returns requests completed this call."""
         done: dict[int, Request] = {}
         parts: dict[int, list[tuple[int, np.ndarray, np.ndarray]]] = {}
+        tracer = current_tracer()
 
         while self._queue:
             if not force and self._pending() < self.batch:
                 oldest = self._queue[0][0].enqueued_at
                 if time.perf_counter() - oldest < self.max_wait:
                     break
-            slots = self._take_slots()
+            with tracer.span("sched.pack"):
+                slots = self._take_slots()
+                if slots:
+                    take = len(slots)
+                    qs = np.stack([r.queries[i] for r, i in slots])
+                    pad = self.batch - take
+                    if pad:
+                        qs = np.pad(qs, ((0, pad), (0, 0)))
             if not slots:
                 continue  # everything popped was shed; re-check the queue
-            take = len(slots)
-            qs = np.stack([r.queries[i] for r, i in slots])
-            pad = self.batch - take
-            if pad:
-                qs = np.pad(qs, ((0, pad), (0, 0)))
             current_chaos().on_engine_step()  # the drill clock: one tick
             #                                   per dispatched batch
+            started = time.perf_counter()
             try:
                 dists, ids = self._dispatch(qs)
             except Exception:
@@ -218,19 +238,21 @@ class BatchScheduler:
             self.stats["batches"] += 1
             self.stats["padded_rows"] += pad
             self.stats["rows"] += take
-            for j, (req, i) in enumerate(slots):
-                req.degraded = req.degraded or degraded
-                parts.setdefault(req.rid, []).append((i, dists[j], ids[j]))
-                if len(parts[req.rid]) == len(req.queries):
-                    order = sorted(parts.pop(req.rid))
-                    req.result = (
-                        np.stack([d for _, d, _ in order]),
-                        np.stack([x for _, _, x in order]),
-                    )
-                    req.status = "served"
-                    req.completed_at = time.perf_counter()
-                    self._count("served")
-                    done[req.rid] = req
+            self._count("wait_s", sum(started - r.enqueued_at for r, _ in slots))
+            with tracer.span("sched.scatter"):
+                for j, (req, i) in enumerate(slots):
+                    req.degraded = req.degraded or degraded
+                    parts.setdefault(req.rid, []).append((i, dists[j], ids[j]))
+                    if len(parts[req.rid]) == len(req.queries):
+                        order = sorted(parts.pop(req.rid))
+                        req.result = (
+                            np.stack([d for _, d, _ in order]),
+                            np.stack([x for _, _, x in order]),
+                        )
+                        req.status = "served"
+                        req.completed_at = time.perf_counter()
+                        self._count("served")
+                        done[req.rid] = req
         return [done[k] for k in sorted(done)]
 
 

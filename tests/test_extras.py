@@ -178,6 +178,40 @@ def test_batch_scheduler_respects_latency_bound():
     assert len(done) == 1
 
 
+def test_batch_scheduler_counts_queue_wait(monkeypatch):
+    """wait_s sums dispatch start minus enqueue over the dispatched rows;
+    rows shed at dispatch add nothing; the registry counter matches."""
+    import time
+
+    from repro.obs import MetricsRegistry
+    from repro.runtime.scheduler import BatchScheduler
+
+    now = [12.0]
+    monkeypatch.setattr(time, "perf_counter", lambda: now[0])
+
+    def step(batch):  # every engine step takes one second of the clock
+        now[0] += 1.0
+        return batch[:, :1], np.zeros((len(batch), 1), np.int32)
+
+    reg = MetricsRegistry()
+    sched = BatchScheduler(step, batch_size=4, registry=reg)
+    r1 = sched.submit(np.ones((3, 2)))
+    r2 = sched.submit(np.ones((6, 2)))
+    late = sched.submit(np.ones((2, 2)))
+    r1.enqueued_at, r2.enqueued_at, late.enqueued_at = 10.0, 11.0, 11.0
+    late.deadline_at = 11.5
+    done = sched.drain()
+    assert [r.rid for r in done] == [r1.rid, r2.rid]
+    assert late.status == "shed_deadline"
+    # Batch 1 starts at 12: r1 x3 waited 2 s, r2 x1 waited 1 s.  Batch 2
+    # at 13: r2 x4, 2 s each.  Batch 3 at 14: r2 x1, 3 s; both rows of
+    # the late request are shed there and count nothing.
+    want = 3 * 2.0 + 1 * 1.0 + 4 * 2.0 + 1 * 3.0
+    assert sched.stats["rows"] == 9 and sched.stats["batches"] == 3
+    assert sched.stats["wait_s"] == want
+    assert reg.counter("serve.queue.wait_s").value == want
+
+
 # ---- int8 KV cache -------------------------------------------------------------
 
 def test_int8_kv_cache_close_to_bf16():

@@ -160,6 +160,9 @@ class TestTracer:
         """The disabled path must allocate nothing: span() returns the
         shared singleton and fence() returns its argument — the zero-cost
         guarantee the engine wave loops rely on."""
+        from jax.profiler import TraceAnnotation
+
+        assert not TraceAnnotation.is_enabled()  # no capture running
         t = NULL_TRACER
         payload = object()
         assert t.fence(payload) is payload
@@ -189,6 +192,58 @@ class TestTracer:
         loop(100)  # warm code objects / caches
         delta(100)
         assert delta(10_000) <= 8
+
+    def test_spans_reach_a_profiler_capture(self, tmp_path):
+        """Under a running capture both tracers' spans are host events of
+        the written .xplane.pb, on the profiler's clock; the recording
+        tracer still keeps its own event for the Chrome export."""
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        from repro.runtime.scheduler import BatchScheduler
+
+        def step(batch):
+            return batch[:, :2], np.zeros((len(batch), 2), np.int32)
+
+        sched = BatchScheduler(step, batch_size=4)
+        sched.submit(np.ones((6, 3)))
+        tr = Tracer()
+        with jax.profiler.trace(str(tmp_path)):
+            with NULL_TRACER.span("obs.null_span", wave=1):
+                pass
+            with tr.span("obs.recorded_span", wave=2):
+                pass
+            sched.drain()
+        paths = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+        assert len(paths) == 1
+        names = [e.name for plane in ProfileData.from_file(paths[0]).planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for e in line.events]
+        for name in ("obs.null_span", "obs.recorded_span"):
+            assert names.count(name) == 1, name
+        # The scheduler's spans: one pack and one scatter per batch.
+        assert sched.stats["batches"] == 2
+        assert names.count("sched.pack") == names.count("sched.scatter") == 2
+        assert [e["name"] for e in tr.events] == ["obs.recorded_span"]
+        assert tr.events[0]["args"] == {"wave": 2}
+        assert NULL_TRACER.span("after") is NULL_TRACER.span("capture")
+
+    def test_null_span_without_jax(self, monkeypatch):
+        """Where jax cannot be imported no capture can run: span() is the
+        shared no-op singleton and nothing raises."""
+        from repro.obs import trace
+
+        monkeypatch.setattr(trace, "_annotation_cls", None)
+        monkeypatch.setitem(sys.modules, "jax.profiler", None)  # import fails
+        s = NULL_TRACER.span("wave", wave=0)
+        assert s is NULL_TRACER.span("other")
+        assert trace._annotation_cls is trace._NoProfiler
+        tr = Tracer()
+        with tr.span("recorded"):
+            pass
+        assert [e["name"] for e in tr.events] == ["recorded"]
 
     def test_span_totals_aggregates_args(self):
         tr = Tracer()
@@ -257,6 +312,40 @@ class TestTracedSearchAcceptance:
         assert tot["graph.wave"]["count"] == st1.waves + 1
         assert tot["graph.launch"]["count"] == st1.waves
         assert tot["graph.merge"]["count"] == st1.waves
+
+    def test_graph_search_under_a_profiler_capture(self, graph_idx, queries,
+                                                    tmp_path):
+        """A graph search under the default tracer while a capture runs:
+        the wave loop's ``annotate`` calls reach the captured span, every
+        wave shows up as a ``graph.wave`` host event, and the results are
+        bit-identical to the search with no capture."""
+        import glob
+
+        import jax
+        import jax.numpy as jnp
+        from jax.profiler import ProfileData
+
+        from repro.index.graph import search_graph_fused
+
+        _, g = graph_idx
+        qj = jnp.asarray(queries)
+        kw = dict(k=5, ef=16, block_q=8, use_ref=True)
+        d0, i0, st0 = search_graph_fused(g, qj, **kw)
+        assert current_tracer() is NULL_TRACER
+        with jax.profiler.trace(str(tmp_path)):
+            d1, i1, st1 = search_graph_fused(g, qj, **kw)
+        assert np.array_equal(np.asarray(i0), np.asarray(i1))
+        assert np.array_equal(np.asarray(d0), np.asarray(d1))
+        assert st0 == st1
+        paths = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+        assert len(paths) == 1
+        names = [e.name for plane in ProfileData.from_file(paths[0]).planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for e in line.events]
+        # One wave span per executed wave plus the terminal width-0 probe.
+        assert names.count("graph.wave") == st1.waves + 1
+        assert names.count("graph.launch") == st1.waves
 
     def test_wave_spans_nest_stage_spans(self, graph_idx, queries):
         """Chrome-trace nesting: every stage event's interval lies inside
