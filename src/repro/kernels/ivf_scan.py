@@ -64,7 +64,10 @@ HBM actually shipped) as well as the dims-consumed quantity fig6/fig7
 track for the host engines.  Tile shapes: compiled mode needs int8 tiles
 of at least (32, 128), so ``block_q >= 32`` and ``D_pad`` a multiple of
 128 on real TPUs (``repro.kernels.ops.min_block_q``); interpret mode (CPU
-tests) accepts smaller tiles.
+tests) accepts smaller tiles.  The IVF route keeps 128-row candidate
+tiles; the flat route, whose every tile is a real contiguous row range,
+launches the wider shape ``ops.flat_tile_shape`` picks under
+``VMEM_LIMIT_BYTES``.
 
 The per-tile stage/merge helpers live in ``repro.kernels.tiles`` and are
 shared with the ``ref.py`` oracle, so kernel-vs-oracle parity — including
@@ -106,8 +109,9 @@ from repro.kernels.tiles import (  # noqa: F401
     stage2_tile,
 )
 
-__all__ = ["ivf_scan_kernel_call", "STATS_COLS",
-           "stage1_tile", "stage2_tile", "merge_topk_tile", "dup_mask"]
+__all__ = ["ivf_scan_kernel_call", "STATS_COLS", "VMEM_LIMIT_BYTES",
+           "vmem_bytes", "stage1_tile", "stage2_tile", "merge_topk_tile",
+           "dup_mask"]
 
 # stats columns: semantic dims-consumed accounting (0-3, unchanged since
 # PR 2) + DMA-granular fetch counters (4-5, tile-level, broadcast to every
@@ -120,6 +124,23 @@ STATS_COLS = (
     "s2_slabs_fetched",  # 4: fp32 (BC, block_d) slabs actually DMA'd
     "s1_tiles_fetched",  # 5: int8 tiles actually DMA'd (fresh real offsets)
 )
+
+# Scoped VMEM every launch asks for: TPU v5e's default scoped limit, named
+# so that the tile rule (``ops.flat_tile_shape``) and the compiler share it.
+VMEM_LIMIT_BYTES = 16 << 20
+# (BQ, BC) f32 temporaries live at once across stage 1, stage 2 and the
+# top-K merge.  Mosaic asked for 21.2 MiB at (64, 4096) with bf16 256-d
+# rows; ``vmem_bytes`` gives 24 MiB there.
+_TILE_TEMPS = 16
+
+
+def vmem_bytes(block_q: int, block_c: int, dim: int, row_dtype) -> int:
+    """Upper estimate of one launch's VMEM working set: the int8 double
+    buffer, the stage-2 landing buffer and its f32 upcast (counted over the
+    whole row, not one slab), and the (block_q, block_c) f32 temporaries."""
+    row_bytes = jnp.dtype(row_dtype).itemsize
+    return (block_c * dim * (2 + row_bytes + 4)
+            + _TILE_TEMPS * block_q * block_c * 4)
 
 
 # ---------------------------------------------------------------------------
@@ -454,6 +475,7 @@ def ivf_scan_kernel_call(
         out_shape=out_shapes,
         compiler_params=CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
     )(

@@ -48,7 +48,7 @@ __all__ = [
     "dco_screen_kernel", "quant_screen_kernel", "ivf_scan_kernel",
     "graph_scan_kernel", "ivf_cap_tiles", "build_window_offsets",
     "block_table", "on_tpu", "auto_interpret", "auto_block_q", "min_block_q",
-    "fused_fetch_totals",
+    "flat_tile_shape", "fused_fetch_totals",
     "graph_vis_words", "unpack_vis", "pow2_bucket", "pad_live_rows",
     "EstimatorSpec", "UnsupportedMethodError", "kernel_spec", "EPS_DISABLED",
 ]
@@ -207,6 +207,45 @@ def auto_block_q(interpret: bool) -> int:
     kernels compile, 8 in interpret mode (tile coherence beats lane
     occupancy there)."""
     return 8 if interpret else min_block_q(jnp.int8)
+
+
+# Candidate-tile widths the flat route tries, widest first.  On one TPU v5e
+# at 1M x 256 bf16 rows and 64-row batches a call took 29.9, 19.0, 11.8 and
+# 9.0 ms at 256, 512, 1024 and 2048 rows (66.2 ms at the IVF route's
+# (32, 128)); 4096 does not fit the scoped VMEM.
+FLAT_BLOCK_C = (2048, 1024, 512, 256, 128)
+
+
+def flat_tile_shape(q: int, wave: int, d_pad: int, row_dtype, *,
+                    interpret: bool = False) -> tuple[int, int]:
+    """(block_q, block_c) of the flat route's fused step.
+
+    Every tile of a flat wave is a real, contiguous row range, so a grid
+    step can screen far more than the IVF route's 128-row tile: the fixed
+    work a step pays (stage-2 entry, slab DMA, top-K merge) then comes
+    once per wide tile.  ``block_q`` is the whole batch when it is a
+    multiple of the mode's query tile (``auto_block_q``) and fits
+    ``ivf_scan.VMEM_LIMIT_BYTES``, else the largest such multiple that
+    divides ``q`` and fits; ``block_c`` is the widest of ``FLAT_BLOCK_C``
+    that divides ``wave`` and fits beside it.
+    """
+    granule = auto_block_q(interpret)
+
+    def fits(bq, bc):
+        return (_ivf_scan.vmem_bytes(bq, bc, d_pad, row_dtype)
+                <= _ivf_scan.VMEM_LIMIT_BYTES)
+
+    block_q = next((bq for bq in range(q - q % granule, 0, -granule)
+                    if q % bq == 0 and fits(bq, FLAT_BLOCK_C[-1])), None)
+    if block_q is None:
+        raise ValueError(f"query_batch {q} has no divisor that is a multiple "
+                         f"of the {granule}-row query tile and fits VMEM")
+    block_c = next((bc for bc in FLAT_BLOCK_C
+                    if wave % bc == 0 and fits(block_q, bc)), None)
+    if block_c is None:
+        raise ValueError(f"wave {wave} is not a multiple of "
+                         f"{FLAT_BLOCK_C[-1]} rows that fit VMEM")
+    return block_q, block_c
 
 
 def block_table(table: EpsilonTable, dim: int, block_d: int):

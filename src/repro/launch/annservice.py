@@ -35,13 +35,9 @@ from repro.distributed.collectives import hierarchical_topk
 
 __all__ = ["build_search_step", "build_graph_engine",
            "build_sharded_graph_engine", "search_input_specs",
-           "autotune_refine_budget", "FUSED_BLOCK_C",
+           "autotune_refine_budget",
            "ContinuousGraphEngine", "ContinuousIVFEngine", "RetiredQuery",
            "SLOPolicy", "parse_slo", "slo_effort", "slo_signal"]
-
-# Candidate-tile rows of the fused megakernel route; serve.py's fetch
-# report normalizes its per-wave figures with the same constant.
-FUSED_BLOCK_C = 128
 
 
 def autotune_refine_budget(scales, sample_rot, *, k: int, wave: int,
@@ -326,8 +322,9 @@ def build_search_step(svc: ServiceConfig, mesh, *, two_phase: bool = True,
     interpret mode (correct but slow there, so tests opt in explicitly).
 
     ``interpret`` is the megakernel's mode; None takes
-    ``ops.auto_interpret()`` (compiled on TPU).  It also fixes the query
-    tile: ``ops.auto_block_q(interpret)`` rows.
+    ``ops.auto_interpret()`` (compiled on TPU).  The fused route's tile
+    shape follows from the step's shapes and that mode
+    (``ops.flat_tile_shape``).
 
     ``with_stats`` (fused route only) appends a third output: a replicated
     (6,) f32 vector of the megakernel's scan counters summed over shards
@@ -335,7 +332,7 @@ def build_search_step(svc: ServiceConfig, mesh, *, two_phase: bool = True,
     driver turns columns 4-5 into the fetched-vs-skipped stage-2 byte
     report per wave.
     """
-    from repro.kernels.ops import auto_block_q, auto_interpret
+    from repro.kernels.ops import auto_interpret, flat_tile_shape
 
     axes = tuple(mesh.axis_names)
     k = svc.k
@@ -570,9 +567,8 @@ def build_search_step(svc: ServiceConfig, mesh, *, two_phase: bool = True,
         if wave % 128 or n_local % wave:
             raise ValueError("fused scan needs wave % 128 == 0 and "
                              "corpus_per_device % wave == 0")
-        block_q = auto_block_q(interpret)
-        if q % block_q:
-            raise ValueError(f"query_batch {q} % block_q {block_q} != 0")
+        block_q, block_c = flat_tile_shape(q, wave, dim, corpus.dtype,
+                                           interpret=interpret)
         if not interpret and block_d % 128:
             raise ValueError(
                 f"compiled fused serving needs delta_d % 128 == 0 "
@@ -587,7 +583,6 @@ def build_search_step(svc: ServiceConfig, mesh, *, two_phase: bool = True,
         qcodes, qscales = quantize_queries_block(qf, block_d)
         q_tiles = q // block_q
         num_waves = n_local // wave
-        block_c = FUSED_BLOCK_C
         cap_tiles = wave // block_c
         base_tiles = jnp.arange(num_waves, dtype=jnp.int32) * cap_tiles
         t_idx = jnp.arange(cap_tiles, dtype=jnp.int32)

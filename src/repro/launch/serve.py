@@ -1292,25 +1292,27 @@ def main(argv: list[str] | None = None) -> dict:
               "requests_shed": shed}
     if with_stats:
         # Demand-paged stage 2: every scanned wave tile ships its int8
-        # block; fp32 moves in (128, Δd) slabs fetched only while stage 2
-        # still has active candidates.  A serving wave spans
-        # wave // 128 candidate tiles, so per-wave figures divide the tile
-        # counters accordingly.
-        from repro.launch.annservice import FUSED_BLOCK_C
+        # block; fp32 moves in (block_c, Δd) slabs fetched only while
+        # stage 2 still has active candidates.  A serving wave spans
+        # wave // block_c candidate tiles, so per-wave figures divide the
+        # tile counters accordingly.
+        from repro.kernels.ops import flat_tile_shape
         from repro.quant.accounting import (
             ID_BYTES, fetched_tile_bytes, stage2_fetch_report,
             two_stage_bytes)
 
+        _, block_c = flat_tile_shape(svc.query_batch, svc.wave, d_pad,
+                                     svc.dtype, interpret=interpret)
         s1_tiles, s2_slabs = scan_totals[5], scan_totals[4]
         fetched, skipped, skip, _ = stage2_fetch_report(
-            s1_tiles, s2_slabs, block_c=FUSED_BLOCK_C, d_pad=d_pad,
+            s1_tiles, s2_slabs, block_c=block_c, d_pad=d_pad,
             block_d=svc.delta_d, fp_bytes=np.dtype(svc.dtype).itemsize)
-        waves = max(s1_tiles / (svc.wave // FUSED_BLOCK_C), 1.0)
+        waves = max(s1_tiles / (svc.wave // block_c), 1.0)
         record_fused_serve_totals(
             reg,
             s1_tiles=float(s1_tiles), s2_slabs=float(s2_slabs),
             s1_bytes=float(fetched_tile_bytes(
-                s1_tiles, block_c=FUSED_BLOCK_C, dims=d_pad,
+                s1_tiles, block_c=block_c, dims=d_pad,
                 bytes_per_dim=1, id_bytes=ID_BYTES)),
             s2_bytes=float(fetched),
             sem_bytes=float(two_stage_bytes(

@@ -105,6 +105,7 @@ def test_block_scale_lower_bound_property(seed, n, d):
     (12, 64, 8, 64, 16, 3),   # Q not a tile multiple
     (5, 40, 4, 32, 8, 2),     # nothing 128-aligned
     (16, 96, 8, 128, 32, 4),  # D padded 96 -> 96 (3 blocks), cap window
+    (16, 64, 16, 512, 16, 2),  # the flat route's wide tile: one query tile
 ])
 def test_fused_kernel_matches_ref(qn, d, block_q, block_c, block_d, n_probe):
     rng = np.random.default_rng(qn + d)
@@ -115,7 +116,8 @@ def test_fused_kernel_matches_ref(qn, d, block_q, block_c, block_d, n_probe):
     rot = np.asarray(est.rotate(jnp.asarray(data)))
     d_pad = (d + block_d - 1) // block_d * block_d
     max_bucket = 200
-    n_pad = (n + max_bucket + 2 * 128 + 127) // 128 * 128
+    tile = max(block_c, 128)
+    n_pad = (n + max_bucket + 2 * tile + tile - 1) // tile * tile
     flat_rot = np.full((n_pad, d_pad), 1e18, np.float32)
     flat_rot[:n, :d] = rot
     flat_rot[:n, d:] = 0.0
@@ -474,3 +476,104 @@ def test_autotune_refine_budget_tracks_band_width():
     assert d_tight["band_width"] < d_coarse["band_width"]
     # near-exact codes need (almost) no slack beyond k itself
     assert b_tight <= 12
+
+
+# ---- the flat route's tile rule --------------------------------------------
+
+@pytest.mark.parametrize("q,wave,d_pad,dtype,interpret", [
+    (64, 4096, 256, jnp.bfloat16, False),   # the serving batch
+    (96, 4096, 256, jnp.bfloat16, False),   # 96 % 32 == 0: one 96-row tile
+    (16, 1024, 64, jnp.float32, True),      # interpret-mode tests
+    (64, 4096, 1024, jnp.float32, False),   # wide rows narrow the tile
+    (1024, 8192, 256, jnp.bfloat16, False),  # a tall batch narrows it too
+    (4096, 8192, 256, jnp.bfloat16, False),  # too tall: 1024-row tiles
+])
+def test_flat_tile_shape_rule(q, wave, d_pad, dtype, interpret):
+    from repro.kernels.ivf_scan import VMEM_LIMIT_BYTES, vmem_bytes
+    from repro.kernels.ops import (
+        FLAT_BLOCK_C, auto_block_q, flat_tile_shape)
+
+    block_q, block_c = flat_tile_shape(q, wave, d_pad, dtype,
+                                       interpret=interpret)
+    assert q % block_q == 0 and block_q % auto_block_q(interpret) == 0
+    assert wave % block_c == 0 and block_c in FLAT_BLOCK_C
+    assert vmem_bytes(block_q, block_c, d_pad, dtype) <= VMEM_LIMIT_BYTES
+    # the widest width that fits beside this query tile
+    wider = [bc for bc in FLAT_BLOCK_C if bc > block_c and wave % bc == 0]
+    assert all(vmem_bytes(block_q, bc, d_pad, dtype) > VMEM_LIMIT_BYTES
+               for bc in wider)
+    if q % auto_block_q(interpret) == 0 and vmem_bytes(
+            q, FLAT_BLOCK_C[-1], d_pad, dtype) <= VMEM_LIMIT_BYTES:
+        assert block_q == q
+
+
+def test_flat_tile_shape_edges():
+    import inspect
+
+    from repro.kernels.ops import (
+        auto_block_q, flat_tile_shape, graph_scan_kernel)
+
+    assert flat_tile_shape(64, 128, 256, jnp.bfloat16) == (64, 128)
+    assert flat_tile_shape(64, 4096 + 128, 256, jnp.bfloat16)[1] == 128
+    with pytest.raises(ValueError, match="query tile"):
+        flat_tile_shape(48 + 8, 4096, 256, jnp.bfloat16)  # 56: no 32-multiple
+    with pytest.raises(ValueError, match="wave"):
+        flat_tile_shape(64, 4096 + 64, 256, jnp.bfloat16)
+    # the other routes keep their own tiles
+    assert (auto_block_q(True), auto_block_q(False)) == (8, 32)
+    ivf = inspect.signature(ivf_scan_kernel).parameters
+    assert (ivf["block_q"].default, ivf["block_c"].default) == (32, 128)
+    graph = inspect.signature(graph_scan_kernel).parameters
+    assert (graph["block_q"].default, graph["block_c"].default) == (8, 32)
+
+
+def test_flat_fused_step_wide_tile_matches_narrow(monkeypatch):
+    """The flat fused step at the rule's shape returns the ids and
+    distances it returns at the IVF route's (query tile, 128) shape, in
+    fewer int8 tile fetches, with recall against exact search no lower."""
+    from jax.sharding import Mesh
+
+    from repro.configs.dade_ivf import ServiceConfig
+    from repro.core import exact_knn
+    from repro.data.pipeline import synthetic_queries, synthetic_vectors
+    from repro.kernels import ops
+    from repro.launch.annservice import build_search_step
+
+    svc = ServiceConfig(corpus_per_device=8192, dim=64, query_batch=16, k=10,
+                        delta_d=32, wave=4096, quant="int8")
+    corpus = synthetic_vectors(svc.corpus_per_device, svc.dim, seed=0,
+                               decay=0.05)
+    queries = synthetic_queries(svc.query_batch, svc.dim, corpus, seed=1)
+    est = build_estimator("dade", corpus[:4000], jax.random.PRNGKey(0),
+                          p_s=svc.p_s, delta_d=svc.delta_d)
+    eps, scale, d_pad, eps_lo = block_table(est.table, svc.dim, svc.delta_d)
+    c_rot = jnp.pad(est.rotate(jnp.asarray(corpus)),
+                    ((0, 0), (0, d_pad - svc.dim)))
+    q_rot = jnp.pad(est.rotate(jnp.asarray(queries)),
+                    ((0, 0), (0, d_pad - svc.dim)))
+    bs = fit_block_scales(c_rot, svc.delta_d)
+    codes = quantize_block(c_rot, bs, svc.delta_d)
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
+
+    def run(shape=None):
+        if shape is not None:
+            monkeypatch.setattr(ops, "flat_tile_shape", lambda *a, **k: shape)
+        step = build_search_step(svc, mesh, quant="int8", fused=True,
+                                 with_stats=True, interpret=True)
+        return [np.asarray(x) for x in
+                jax.jit(step)(c_rot, codes, bs, q_rot, eps, scale, eps_lo)]
+
+    wide = ops.flat_tile_shape(svc.query_batch, svc.wave, d_pad, c_rot.dtype,
+                               interpret=True)
+    assert wide == (16, 2048)
+    d_w, i_w, st_w = run()
+    d_n, i_n, st_n = run((ops.auto_block_q(True), 128))
+    assert np.array_equal(i_w, i_n)
+    np.testing.assert_allclose(d_w, d_n, rtol=1e-6, atol=1e-6)
+    _, gt = exact_knn(jnp.asarray(queries), jnp.asarray(corpus), svc.k)
+    assert _recall(i_w, gt) >= _recall(i_n, gt) >= 0.9
+    # int8 tiles DMA'd: one 16-row query tile over 2048-row tiles against
+    # two 8-row tiles over 128-row tiles
+    n_tiles = svc.corpus_per_device
+    assert st_w[5] == n_tiles // 2048 and st_n[5] == 2 * n_tiles // 128
+    assert st_w[2] == st_n[2] == svc.query_batch * svc.corpus_per_device

@@ -4,7 +4,8 @@ described, not attached.
 The TPU compiler refuses what interpret mode accepts (block shapes off the
 tiling grid, fast-memory overruns, unsupported dot precisions), so these
 tests lower and compile the megakernels and the flat fused search step at
-serving widths — 1M rows x 256 dims, Δd=128, 32-row query tiles — and
+serving widths — 1M rows x 256 dims, Δd=128, the IVF route's 32 x 128
+tiles and the flat route's wider ones — and
 check that the Pallas kernel is in the compiled program.  Nothing runs.
 
 The topology and everything built from it lives in fixtures of this file:
@@ -68,15 +69,22 @@ def _compiled_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("k", [10, 100])
-def test_ivf_scan_compiles_at_serving_width(one_chip, k):
+@pytest.mark.parametrize("k,flat", [
+    pytest.param(10, False, id="10"), pytest.param(100, False, id="100"),
+    pytest.param(10, True, id="flat-10")])
+def test_ivf_scan_compiles_at_serving_width(one_chip, k, flat):
+    """At the IVF route's (32, 128) tile, and at the tile the flat route's
+    rule picks for the serving batch (64 queries, wave 4096, bf16 rows)."""
     from repro.kernels.ivf_scan import ivf_scan_kernel_call
+    from repro.kernels.ops import flat_tile_shape
 
     qn, wave = 64, 4096
-    cap_tiles = wave // 128
+    block_q, block_c = (flat_tile_shape(qn, wave, DIM, jnp.bfloat16)
+                        if flat else (BLOCK_Q, 128))
+    cap_tiles = wave // block_c
     s = functools.partial(_sds, sharding=one_chip)
     args = (
-        s((qn // BLOCK_Q, ROWS // wave, cap_tiles), jnp.int32),  # tile offs
+        s((qn // block_q, ROWS // wave, cap_tiles), jnp.int32),  # tile offs
         s((qn, DIM), jnp.int8), s((qn, DIM), jnp.float32),
         s((qn, S_STEPS), jnp.float32), s((qn,), jnp.float32),
         s((qn, k), jnp.float32), s((qn, k), jnp.int32),
@@ -86,7 +94,7 @@ def test_ivf_scan_compiles_at_serving_width(one_chip, k):
         s((S_STEPS,), jnp.float32),
     )
     fn = functools.partial(
-        ivf_scan_kernel_call, k=k, block_q=BLOCK_Q, block_c=128,
+        ivf_scan_kernel_call, k=k, block_q=block_q, block_c=block_c,
         block_d=BLOCK_D, cap_tiles=cap_tiles, interpret=False)
     assert "tpu_custom_call" in _compiled_text(fn, *args)
 
