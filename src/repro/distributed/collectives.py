@@ -70,7 +70,10 @@ def hierarchical_topk(
     """Merge per-shard top-k along mesh axes one at a time (tree reduce).
 
     Called inside shard_map.  Each hop gathers (A, Q, K) then re-selects K —
-    payload per link stays Q*K instead of Q*K*prod(axes).
+    payload per link stays Q*K instead of Q*K*prod(axes).  This is the
+    row-sharded flat route's cross-chip merge: ``build_search_step`` runs
+    it under ``jax.named_scope("exchange.merge")``, and
+    ``quant.accounting.flat_exchange_bytes`` counts what it moves.
     """
     sq, ids = local_sq, local_ids
     for ax in axis_names:

@@ -375,8 +375,9 @@ def build_search_step(svc: ServiceConfig, mesh, *, two_phase: bool = True,
         exact_sq = jnp.sum(diff * diff, axis=-1)
         kth_local = jnp.max(exact_sq, axis=1)
         r0 = kth_local
-        for ax in axes:
-            r0 = jax.lax.pmin(r0, ax)
+        with jax.named_scope("exchange.seed"):
+            for ax in axes:
+                r0 = jax.lax.pmin(r0, ax)
         # Widen by the first ENABLED checkpoint's overshoot band: a
         # blocked schedule whose early checkpoints are disabled (the
         # EPS_DISABLED sentinel — fdscanning under a small block_d) must
@@ -385,6 +386,13 @@ def build_search_step(svc: ServiceConfig, mesh, *, two_phase: bool = True,
         # reassociation (see core.estimators).
         return (r0 * (1.0 + first_enabled_eps(eps)) ** 2
                 * (1.0 + SEED_SLACK))
+
+    def merge_shards(top_sq, top_ids):
+        """Hierarchical cross-shard top-K merge (innermost axis first:
+        cheapest links carry the most traffic at TPU topology
+        granularity), named ``exchange.merge`` in every body."""
+        with jax.named_scope("exchange.merge"):
+            return hierarchical_topk(top_sq, top_ids, tuple(reversed(axes)), k)
 
     def local_search(corpus, queries, eps, scale, eps_lo):
         """Per-shard screen. corpus: (N_local, D). Runs inside shard_map."""
@@ -472,9 +480,7 @@ def build_search_step(svc: ServiceConfig, mesh, *, two_phase: bool = True,
         bases = jnp.arange(num_waves, dtype=jnp.int32) * wave
         (top_sq, top_ids, _), _ = jax.lax.scan(body, init, (corpus_w, bases))
 
-        # Hierarchical cross-shard merge (innermost axis first: cheapest links
-        # carry the most traffic at TPU topology granularity).
-        top_sq, top_ids = hierarchical_topk(top_sq, top_ids, tuple(reversed(axes)), k)
+        top_sq, top_ids = merge_shards(top_sq, top_ids)
         return jnp.sqrt(jnp.maximum(top_sq, 0.0)), top_ids
 
     def local_search_quant(corpus, codes, scales, queries, eps, scale, eps_lo):
@@ -546,7 +552,7 @@ def build_search_step(svc: ServiceConfig, mesh, *, two_phase: bool = True,
         (top_sq, top_ids, _), _ = jax.lax.scan(
             body, init, (corpus_w, codes_w, bases))
 
-        top_sq, top_ids = hierarchical_topk(top_sq, top_ids, tuple(reversed(axes)), k)
+        top_sq, top_ids = merge_shards(top_sq, top_ids)
         return jnp.sqrt(jnp.maximum(top_sq, 0.0)), top_ids
 
     def local_search_quant_fused(corpus, codes, bscales, queries, eps, scale,
@@ -599,8 +605,7 @@ def build_search_step(svc: ServiceConfig, mesh, *, two_phase: bool = True,
             block_d=block_d, cap_tiles=cap_tiles,
             interpret=interpret)
         top_ids = jnp.where(top_ids >= 0, base + top_ids, -1)
-        top_sq, top_ids = hierarchical_topk(
-            top_sq, top_ids, tuple(reversed(axes)), k)
+        top_sq, top_ids = merge_shards(top_sq, top_ids)
         dists = jnp.sqrt(jnp.maximum(top_sq, 0.0))
         if not with_stats:
             return dists, top_ids

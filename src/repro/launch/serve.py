@@ -233,7 +233,7 @@ def main(argv: list[str] | None = None) -> dict:
     from repro.obs import (
         MetricsRegistry, Tracer, set_tracer, write_chrome_trace,
         write_metrics_json, record_graph_scan, record_graph_sharded,
-        record_fused_serve_totals, record_dco_method,
+        record_flat_exchange, record_fused_serve_totals, record_dco_method,
     )
     from repro.obs.trace import current_tracer
 
@@ -1276,7 +1276,19 @@ def main(argv: list[str] | None = None) -> dict:
         prep(synthetic_queries(svc.query_batch, svc.dim, corpus, seed=999)))
     scan_totals[:] = 0.0
 
-    sched = make_scheduler(fixed_step)
+    # Every timed flat step moves its seed pmin and top-K merge between the
+    # mesh's devices (0 bytes on one device).
+    from repro.quant.accounting import flat_exchange_bytes
+
+    step_exchange = flat_exchange_bytes(
+        axis_sizes=mesh.devices.shape, queries=svc.query_batch, k=svc.k)
+
+    def exchanged_step(batch_np):
+        out = fixed_step(batch_np)
+        record_flat_exchange(reg, exchanged_bytes=step_exchange)
+        return out
+
+    sched = make_scheduler(exchanged_step)
     payloads = make_payloads(prep)
     reqs, gts, dt, lat_ms = drive(sched, payloads)
     served, shed = serve_accounting(sched, reqs, gts)

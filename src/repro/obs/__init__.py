@@ -20,8 +20,8 @@ Catalogue and worked examples: ``docs/OBSERVABILITY.md``.
 from repro.obs.metrics import (  # noqa: F401
     Counter, Gauge, Histogram, MetricsRegistry, merge_snapshots,
     LATENCY_BUCKETS_MS, record_fused_scan, record_graph_scan,
-    record_graph_sharded, record_fused_serve_totals, record_mutations,
-    record_drift, record_dco_method, DCO_METHODS,
+    record_graph_sharded, record_flat_exchange, record_fused_serve_totals,
+    record_mutations, record_drift, record_dco_method, DCO_METHODS,
 )
 from repro.obs.trace import (  # noqa: F401
     Tracer, NullTracer, NULL_TRACER, current_tracer, set_tracer, use_tracer,

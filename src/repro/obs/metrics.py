@@ -36,6 +36,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "merge_snapshots",
     "LATENCY_BUCKETS_MS", "WAVE_DEPTH_BUCKETS",
     "record_fused_scan", "record_graph_scan", "record_graph_sharded",
+    "record_flat_exchange",
     "record_fused_serve_totals", "record_mutations", "record_drift",
     "record_dco_method", "DCO_METHODS",
 ]
@@ -335,6 +336,14 @@ def record_graph_sharded(reg: MetricsRegistry, st, *, queries: int) -> None:
             st.tombstoned_nodes)
         reg.gauge("graph.sharded.degraded.num_dead").set(
             float(len(st.dead_shards)))
+
+
+def record_flat_exchange(reg: MetricsRegistry, *, exchanged_bytes: float
+                         ) -> None:
+    """Feed one row-sharded flat search step's cross-shard bytes
+    (``accounting.flat_exchange_bytes``: the seed's ``pmin`` and the
+    top-K merge) into the exchange regime, beside the sharded graph's."""
+    reg.counter("dco.exchanged.bytes").add(exchanged_bytes)
 
 
 def record_mutations(reg: MetricsRegistry, ledger, *,

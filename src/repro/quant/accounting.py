@@ -28,13 +28,17 @@ definitions drift.  This module owns both accounting regimes:
     beam windows / thresholds / visited bitmaps + scattered frontier
     offsets).  Interconnect traffic, not HBM — reported as its own column
     by ``index.graph.GraphShardedStats``, fig9, and the sharded serve
-    report.
+    report.  The row-sharded flat route's share
+    (``flat_exchange_bytes``: the seed's ``pmin`` and the top-K merge's
+    all-gathers) feeds the same counter.
 
 ``benchmarks.common`` re-exports these helpers for the figure scripts; the
 host engines import them directly (src must not depend on benchmarks).
 """
 
 from __future__ import annotations
+
+import math
 
 INT8_BYTES = 1   # stage-1 code stream, bytes per dimension
 FP32_BYTES = 4   # stage-2 exact rows, bytes per dimension
@@ -44,6 +48,7 @@ __all__ = [
     "INT8_BYTES", "FP32_BYTES", "ID_BYTES",
     "two_stage_bytes", "fetched_tile_bytes", "row_gather_bytes",
     "stage2_skip_rate", "stage2_fetch_report", "frontier_exchange_bytes",
+    "flat_exchange_bytes",
 ]
 
 
@@ -112,6 +117,26 @@ def frontier_exchange_bytes(*, num_shards: int, queries: int, ef: int,
     gathered = num_shards * (num_shards - 1) * payload
     scattered = num_shards * q_tiles * steps * 4
     return float(gathered + scattered)
+
+
+def flat_exchange_bytes(*, axis_sizes, queries: int, k: int) -> float:
+    """Cross-shard bytes of ONE row-sharded flat search step
+    (``launch.annservice.build_search_step`` over a mesh of ``axis_sizes``).
+
+    Per mesh axis of size A, each of the mesh's devices receives from its
+    A−1 peers on that axis:
+
+      * **the seed** (``exchange.seed``): the (Q,) f32 threshold ``pmin``;
+      * **the merge** (``exchange.merge``): one hop of
+        ``hierarchical_topk``, the (Q, K) f32 distances plus i32 ids.
+
+    Counted as ``frontier_exchange_bytes`` counts, summed over every
+    device: devices × (A−1) × payload per axis.  One device, or an axis of
+    size 1, exchanges nothing.
+    """
+    payload = queries * FP32_BYTES + queries * k * (FP32_BYTES + ID_BYTES)
+    devices = math.prod(axis_sizes)
+    return float(sum(devices * (a - 1) * payload for a in axis_sizes))
 
 
 def stage2_skip_rate(s2_slabs_fetched, s2_slabs_total) -> float:

@@ -146,7 +146,11 @@ def test_flat_fused_search_step_compiles(topo, chips):
     step = build_search_step(svc, mesh, quant="int8", fused=True,
                              with_stats=True, interpret=False)
     compiled = jax.jit(step).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # The cross-chip exchange is named; on one chip it folds away.
+    named = ("exchange.seed/pmin" in text, "exchange.merge/all_gather" in text)
+    assert named == ((True, True) if chips > 1 else (False, False))
     # corpus rows (bf16) + int8 codes per chip, as serve places them
     per_chip = ROWS * DIM * (jnp.dtype(svc.dtype).itemsize + 1)
     assert compiled.memory_analysis().argument_size_in_bytes >= per_chip
