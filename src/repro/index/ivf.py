@@ -454,7 +454,7 @@ def _fused_stats(index: IVFIndex, stats, *, qn: int, k: int, block_q: int,
                  block_c: int, seed_r: bool) -> FusedScanStats:
     """FusedScanStats epilogue from raw kernel stats rows.
 
-    One place turns the (Q, 6) counters into the per-query ledger, shared
+    One place turns the (Q, 7) counters into the per-query ledger, shared
     by ``search_ivf_fused`` and the continuous engine so a solo slot's
     ledger is built by the same arithmetic the batch oracle uses (the stat
     columns are integer-valued f32 — sums are exact, so the ledgers compare

@@ -47,7 +47,7 @@ to "probe a data-dependent frontier":
     serving (the scalar-prefetched ``vis_base`` shifts local tile offsets
     into the global node id space, so every shard marks the same global
     bitmap).  Marking changes no results — re-screens were already sound
-    (r never loosens, ``dup_mask`` blocks double admission); the bitmap
+    (r never loosens, ``merge_topk_tile`` blocks double admission); the bitmap
     only moves who owns the mask.
   * **Frozen-threshold (sharded) mode.**  ``tighten=False`` skips the
     in-wave r² tightening after each merge: every expansion of the launch
@@ -103,7 +103,7 @@ from repro.kernels._compat import ANY_MEMSPACE, CompilerParams
 # Same column semantics as the IVF megakernel — one ledger, two kernels.
 from repro.kernels.ivf_scan import STATS_COLS  # noqa: F401  (re-export)
 from repro.kernels.tiles import (
-    dup_mask, merge_topk_tile, stage1_tile, stage2_need, stage2_slab,
+    merge_topk_tile, stage1_tile, stage2_need, stage2_slab,
 )
 
 __all__ = ["graph_scan_kernel_call", "STATS_COLS"]
@@ -133,13 +133,13 @@ def _kernel(
     # outputs
     top_sq_ref,  # (QT, EF) f32
     top_ids_ref,  # (QT, EF) i32
-    stats_ref,  # (QT, 6) f32 — see STATS_COLS
+    stats_ref,  # (QT, 7) f32 — see STATS_COLS
     vis_ref,  # (1, W) i32 — bitmap with this wave's expansions marked
     # scratch
     top_sq_s,  # (QT, EF) f32 VMEM
     top_ids_s,  # (QT, EF) i32 VMEM
     rsq_s,  # (QT, 1) f32 VMEM
-    stats_s,  # (QT, 6) f32 VMEM
+    stats_s,  # (QT, 7) f32 VMEM
     vis_s,  # (1, W) i32 VMEM — visited bitmap carried across the wave
     codes_buf,  # (2, BC, D) int8 VMEM — stage-1 double buffer
     rows_buf,  # (BC, D) fp VMEM — stage-2 landing buffer
@@ -253,7 +253,7 @@ def _kernel(
         one = jnp.ones_like(d8_sum)
         s1_fetched = jnp.where(fresh, one, zero)
         stats_s[...] += jnp.concatenate(
-            [d8_sum, zero, nvalid, zero, zero, s1_fetched], axis=1)
+            [d8_sum, zero, nvalid, zero, zero, s1_fetched, zero], axis=1)
 
         alive = jnp.sum((active8 & valid).astype(jnp.int32))
 
@@ -296,18 +296,15 @@ def _kernel(
             ok = passed & valid
             d32_sum = jnp.sum(d32 * validf, axis=1, keepdims=True)
             npass = jnp.sum(ok.astype(jnp.float32), axis=1, keepdims=True)
-            z = jnp.zeros_like(d32_sum)
-            slabs = jnp.broadcast_to(slab_cnt, d32_sum.shape)
-            stats_s[...] += jnp.concatenate([z, d32_sum, z, npass, slabs, z],
-                                            axis=1)
-
-            dup = dup_mask(ids, top_ids_s[...], k=ef)
-            new_sq = jnp.where(ok & ~dup, exact_sq, jnp.inf)
-            top_sq, top_ids = merge_topk_tile(
-                top_sq_s[...], top_ids_s[...], new_sq, ids, k=ef
-            )
+            top_sq, top_ids, rounds = merge_topk_tile(
+                top_sq_s[...], top_ids_s[...], exact_sq, ok, ids)
             top_sq_s[...] = top_sq
             top_ids_s[...] = top_ids
+            z = jnp.zeros_like(d32_sum)
+            slabs = jnp.broadcast_to(slab_cnt, d32_sum.shape)
+            rounds = jnp.broadcast_to(rounds.astype(jnp.float32), z.shape)
+            stats_s[...] += jnp.concatenate(
+                [z, d32_sum, z, npass, slabs, z, rounds], axis=1)
             # r² = the (thresh_col+1)-th best of the window — the K-th for
             # the paper's HNSW++-style decoupled threshold (default), the
             # EF-th for the coupled variant; tightens across the wave's
@@ -369,7 +366,7 @@ def graph_scan_kernel_call(
     its sizing (words padded to the lane grid).
 
     Returns (top_sq (Q, EF) f32 ascending, top_ids (Q, EF) i32,
-    stats (Q, 6) f32 — see ``STATS_COLS``, vis (q_tiles, W) i32); feed
+    stats (Q, 7) f32 — see ``STATS_COLS``, vis (q_tiles, W) i32); feed
     top/r²/vis back in as the next wave's carried state to continue the
     beam.  ``tighten=False`` freezes the screen threshold at ``r0_sq`` for
     the whole launch (sharded wave semantics — see the module docstring).

@@ -105,17 +105,16 @@ from repro.kernels._compat import ANY_MEMSPACE, CompilerParams
 # ending in the slab-fetch count) — import from repro.kernels.tiles for
 # the canonical API.
 from repro.kernels.tiles import (  # noqa: F401
-    dup_mask, merge_topk_tile, stage1_tile, stage2_need, stage2_slab,
-    stage2_tile,
+    merge_topk_tile, stage1_tile, stage2_need, stage2_slab, stage2_tile,
 )
 
 __all__ = ["ivf_scan_kernel_call", "STATS_COLS", "VMEM_LIMIT_BYTES",
-           "vmem_bytes", "stage1_tile", "stage2_tile", "merge_topk_tile",
-           "dup_mask"]
+           "vmem_bytes", "stage1_tile", "stage2_tile", "merge_topk_tile"]
 
-# stats columns: semantic dims-consumed accounting (0-3, unchanged since
-# PR 2) + DMA-granular fetch counters (4-5, tile-level, broadcast to every
-# query row of the tile so the oracle can assert them elementwise).
+# stats columns: semantic dims-consumed accounting (0-3) + tile-level
+# counters (4-6: DMA-granular fetches and the top-K merge's rounds),
+# broadcast to every query row of the tile so the oracle can assert them
+# elementwise.
 STATS_COLS = (
     "int8_dims",        # 0: int8 dims consumed (retirement checkpoints)
     "fp32_dims",        # 1: fp32 dims consumed by stage-2 survivors
@@ -123,6 +122,7 @@ STATS_COLS = (
     "rows_passed",      # 3: rows surviving the full screen
     "s2_slabs_fetched",  # 4: fp32 (BC, block_d) slabs actually DMA'd
     "s1_tiles_fetched",  # 5: int8 tiles actually DMA'd (fresh real offsets)
+    "merge_rounds",     # 6: insertion rounds of tiles.merge_topk_tile
 )
 
 # Scoped VMEM every launch asks for: TPU v5e's default scoped limit, named
@@ -168,12 +168,12 @@ def _kernel(
     # outputs
     top_sq_ref,  # (QT, K) f32
     top_ids_ref,  # (QT, K) i32
-    stats_ref,  # (QT, 6) f32 — see STATS_COLS
+    stats_ref,  # (QT, 7) f32 — see STATS_COLS
     # scratch
     top_sq_s,  # (QT, K) f32 VMEM
     top_ids_s,  # (QT, K) i32 VMEM
     rsq_s,  # (QT, 1) f32 VMEM
-    stats_s,  # (QT, 6) f32 VMEM
+    stats_s,  # (QT, 7) f32 VMEM
     codes_buf,  # (2, CT, D) int8 VMEM — stage-1 double buffer
     rows_buf,  # (CT, D) fp VMEM — stage-2 landing buffer
     slot_s,  # (1, 2) i32 SMEM — [slot cursor, last issued offset]
@@ -279,7 +279,7 @@ def _kernel(
         one = jnp.ones_like(d8_sum)
         s1_fetched = jnp.where(fresh, one, zero)
         stats_s[...] += jnp.concatenate(
-            [d8_sum, zero, nvalid, zero, zero, s1_fetched], axis=1)
+            [d8_sum, zero, nvalid, zero, zero, s1_fetched, zero], axis=1)
 
         alive = jnp.sum((active8 & valid).astype(jnp.int32))
 
@@ -328,18 +328,15 @@ def _kernel(
             ok = passed & valid
             d32_sum = jnp.sum(d32 * validf, axis=1, keepdims=True)
             npass = jnp.sum(ok.astype(jnp.float32), axis=1, keepdims=True)
-            z = jnp.zeros_like(d32_sum)
-            slabs = jnp.broadcast_to(slab_cnt, d32_sum.shape)
-            stats_s[...] += jnp.concatenate([z, d32_sum, z, npass, slabs, z],
-                                            axis=1)
-
-            dup = dup_mask(ids, top_ids_s[...], k=k)
-            new_sq = jnp.where(ok & ~dup, exact_sq, jnp.inf)
-            top_sq, top_ids = merge_topk_tile(
-                top_sq_s[...], top_ids_s[...], new_sq, ids, k=k
-            )
+            top_sq, top_ids, rounds = merge_topk_tile(
+                top_sq_s[...], top_ids_s[...], exact_sq, ok, ids)
             top_sq_s[...] = top_sq
             top_ids_s[...] = top_ids
+            z = jnp.zeros_like(d32_sum)
+            slabs = jnp.broadcast_to(slab_cnt, d32_sum.shape)
+            rounds = jnp.broadcast_to(rounds.astype(jnp.float32), z.shape)
+            stats_s[...] += jnp.concatenate(
+                [z, d32_sum, z, npass, slabs, z, rounds], axis=1)
             # Threshold tightens between waves on device — no host
             # round-trip.
             rsq_s[...] = jnp.minimum(rsq_s[...], top_sq[:, k - 1:k])
@@ -388,7 +385,7 @@ def ivf_scan_kernel_call(
     candidate tiles in manually.
 
     Returns (top_sq (Q, K) f32 ascending, top_ids (Q, K) i32,
-    stats (Q, 6) f32 — see ``STATS_COLS``).
+    stats (Q, 7) f32 — see ``STATS_COLS``).
     """
     qn, dim = q_rot.shape
     n_pad = flat_rot.shape[0]

@@ -473,9 +473,9 @@ def ivf_scan_kernel(
     (``ivf_scan.STATS_COLS``) count the fp32/int8 tiles actually DMA'd —
     ``fused_fetch_totals`` aggregates them for byte accounting.
 
-    Returns (top_sq (Q, K) ascending, top_ids (Q, K), stats (Q, 6) f32 =
+    Returns (top_sq (Q, K) ascending, top_ids (Q, K), stats (Q, 7) f32 =
     [int8 dims, fp32 dims, rows scanned, passed rows, s2 tiles fetched,
-    s1 tiles fetched]), cropped to Q.
+    s1 tiles fetched, merge rounds]), cropped to Q.
     """
     if interpret is None:
         interpret = auto_interpret()
@@ -611,7 +611,7 @@ def graph_scan_kernel(
     padded to ``block_q`` rows with inf/-1 window entries and r²=0, so pad
     rows prune instantly and never touch the outputs.
 
-    Returns (top_sq (Q, EF) ascending, top_ids (Q, EF), stats (Q, 6) f32 =
+    Returns (top_sq (Q, EF) ascending, top_ids (Q, EF), stats (Q, 7) f32 =
     ``ivf_scan.STATS_COLS``, vis (q_tiles, W) i32), cropped to Q — feed
     top/r²/vis back in to continue the beam next wave (``unpack_vis``
     turns the bitmap into the frontier-selection mask).

@@ -171,9 +171,8 @@ def ivf_scan_ref(
     VMEM/SMEM — so tests can replay each wave against ``dco_screen_batch``
     and assert that no tile with survivors is ever elided.
     """
-    from repro.kernels.tiles import (
-        dup_mask, merge_topk_tile, stage1_tile, stage2_tile,
-    )
+    from repro.kernels.ivf_scan import STATS_COLS
+    from repro.kernels.tiles import merge_topk_tile, stage1_tile, stage2_tile
 
     qn, dim = q_rot.shape
     q_tiles = qn // block_q
@@ -187,7 +186,7 @@ def ivf_scan_ref(
         t_sq = jnp.asarray(top0_sq[qs], jnp.float32)
         t_ids = jnp.asarray(top0_ids[qs], jnp.int32)
         rsq = r0_sq[qs].reshape(-1, 1).astype(jnp.float32)
-        st = jnp.zeros((block_q, 6), jnp.float32)
+        st = jnp.zeros((block_q, len(STATS_COLS)), jnp.float32)
         last_off = None  # last issued offset — the kernel's reuse cursor
         for p in range(num_probes):
             for t in range(cap_tiles):
@@ -212,7 +211,7 @@ def ivf_scan_ref(
                 one = jnp.ones_like(d8_sum)
                 s1f = one if fresh else zero
                 st = st + jnp.concatenate(
-                    [d8_sum, zero, nvalid, zero, zero, s1f], axis=1)
+                    [d8_sum, zero, nvalid, zero, zero, s1f, zero], axis=1)
                 alive = int(jnp.sum((active8 & valid).astype(jnp.int32)))
                 rec = dict(tile=i, probe=p, ctile=t, row_start=off * block_c,
                            ids=ids[0], rsq=rsq_frozen[:, 0], active8=active8,
@@ -228,13 +227,15 @@ def ivf_scan_ref(
                     ok = passed & valid
                     d32_sum = jnp.sum(d32 * validf, axis=1, keepdims=True)
                     npass = jnp.sum(ok.astype(jnp.float32), axis=1, keepdims=True)
+                    t_sq, t_ids, rounds = merge_topk_tile(
+                        t_sq, t_ids, exact_sq, ok, ids)
                     z = jnp.zeros_like(d32_sum)
                     slabs_col = jnp.broadcast_to(slabs, d32_sum.shape)
+                    rounds_col = jnp.broadcast_to(
+                        rounds.astype(jnp.float32), d32_sum.shape)
                     st = st + jnp.concatenate(
-                        [z, d32_sum, z, npass, slabs_col, z], axis=1)
-                    dup = dup_mask(ids, t_ids, k=k)
-                    new_sq = jnp.where(ok & ~dup, exact_sq, jnp.inf)
-                    t_sq, t_ids = merge_topk_tile(t_sq, t_ids, new_sq, ids, k=k)
+                        [z, d32_sum, z, npass, slabs_col, z, rounds_col],
+                        axis=1)
                     rsq = jnp.minimum(rsq, t_sq[:, k - 1:k])
                     rec.update(passed=passed, exact_sq=exact_sq,
                                slabs=float(slabs))
@@ -273,14 +274,14 @@ def _graph_step_stage1(qcodes, qscales, codes, bscales, eps, scale, rsq, ids,
     return active8, d8_sum, nvalid, alive
 
 
-@partial(jax.jit, static_argnames=("block_d", "ef", "thresh_col", "tighten"))
+@partial(jax.jit, static_argnames=("block_d", "thresh_col", "tighten"))
 def _graph_step_stage2(q, rows, eps, scale, rsq, active8, ids, t_sq, t_ids,
-                       *, block_d: int, ef: int, thresh_col: int,
-                       tighten: bool):
+                       *, block_d: int, thresh_col: int, tighten: bool):
     """Stage 2 and the window merge of one expansion step of
     ``graph_scan_ref`` (compiled per step, as ``_graph_step_stage1``).
-    Returns (t_sq, t_ids, rsq, d32_sum, npass, slabs, passed, exact_sq)."""
-    from repro.kernels.tiles import dup_mask, merge_topk_tile, stage2_tile
+    Returns (t_sq, t_ids, rsq, d32_sum, npass, slabs, rounds, passed,
+    exact_sq)."""
+    from repro.kernels.tiles import merge_topk_tile, stage2_tile
 
     valid = ids >= 0
     exact_sq, passed, d32, slabs = stage2_tile(
@@ -288,12 +289,10 @@ def _graph_step_stage2(q, rows, eps, scale, rsq, active8, ids, t_sq, t_ids,
     ok = passed & valid
     d32_sum = jnp.sum(d32 * valid.astype(jnp.float32), axis=1, keepdims=True)
     npass = jnp.sum(ok.astype(jnp.float32), axis=1, keepdims=True)
-    dup = dup_mask(ids, t_ids, k=ef)
-    new_sq = jnp.where(ok & ~dup, exact_sq, jnp.inf)
-    t_sq, t_ids = merge_topk_tile(t_sq, t_ids, new_sq, ids, k=ef)
+    t_sq, t_ids, rounds = merge_topk_tile(t_sq, t_ids, exact_sq, ok, ids)
     if tighten:
         rsq = jnp.minimum(rsq, t_sq[:, thresh_col:thresh_col + 1])
-    return t_sq, t_ids, rsq, d32_sum, npass, slabs, passed, exact_sq
+    return t_sq, t_ids, rsq, d32_sum, npass, slabs, rounds, passed, exact_sq
 
 
 def graph_scan_ref(
@@ -350,6 +349,8 @@ def graph_scan_ref(
     """
     import numpy as np
 
+    from repro.kernels.ivf_scan import STATS_COLS
+
     qn, dim = q_rot.shape
     if thresh_col is None:
         thresh_col = ef - 1
@@ -365,7 +366,7 @@ def graph_scan_ref(
         t_sq = jnp.asarray(top0_sq[qs], jnp.float32)
         t_ids = jnp.asarray(top0_ids[qs], jnp.int32)
         rsq = r0_sq[qs].reshape(-1, 1).astype(jnp.float32)
-        st = jnp.zeros((block_q, 6), jnp.float32)
+        st = jnp.zeros((block_q, len(STATS_COLS)), jnp.float32)
         tile_qcodes, tile_qscales, tile_q = qcodes[qs], qscales[qs], q_rot[qs]
         last_off = None  # last issued offset — the kernel's reuse cursor
         for s in range(num_steps):
@@ -386,22 +387,24 @@ def graph_scan_ref(
             one = jnp.ones_like(d8_sum)
             s1f = one if fresh else zero
             st = st + jnp.concatenate(
-                [d8_sum, zero, nvalid, zero, zero, s1f], axis=1)
+                [d8_sum, zero, nvalid, zero, zero, s1f, zero], axis=1)
             alive = int(alive)
             rec = dict(tile=i, step=s, row_start=off * block_c,
                        ids=ids[0], rsq=rsq_frozen[:, 0], active8=active8,
                        valid=ids[0] >= 0, alive=alive, fetched=alive > 0,
                        fresh=fresh, slabs=0.0, marked=goff)
             if alive > 0:
-                (t_sq, t_ids, rsq, d32_sum, npass, slabs, passed,
+                (t_sq, t_ids, rsq, d32_sum, npass, slabs, rounds, passed,
                  exact_sq) = _graph_step_stage2(
                     tile_q, adj_rot[rows], eps, scale, rsq_frozen, active8,
-                    ids, t_sq, t_ids, block_d=block_d, ef=ef,
+                    ids, t_sq, t_ids, block_d=block_d,
                     thresh_col=thresh_col, tighten=tighten)
                 z = jnp.zeros_like(d32_sum)
                 slabs_col = jnp.broadcast_to(slabs, d32_sum.shape)
+                rounds_col = jnp.broadcast_to(
+                    rounds.astype(jnp.float32), d32_sum.shape)
                 st = st + jnp.concatenate(
-                    [z, d32_sum, z, npass, slabs_col, z], axis=1)
+                    [z, d32_sum, z, npass, slabs_col, z, rounds_col], axis=1)
                 rec.update(passed=passed, exact_sq=exact_sq,
                            slabs=float(slabs))
             else:

@@ -14,7 +14,8 @@ contract table lives in ``docs/ARCHITECTURE.md`` §2):
   * ``dade_threshold`` — the hypothesis-test threshold ``(1+ε)²·r²``.
   * ``stage1_tile`` / ``stage2_tile`` — the fused kernel's two screening
     stages over one (BQ, BC) candidate tile.
-  * ``merge_topk_tile`` / ``dup_mask`` — the on-device top-K maintenance.
+  * ``merge_topk_tile`` — the on-device top-K maintenance: entrants are
+    inserted one round at a time, duplicates checked on those rows alone.
 
 Everything here is pure jnp (no pallas primitives), so the same functions
 trace inside a Mosaic kernel body, in interpret mode, and in the eager
@@ -29,7 +30,7 @@ import jax.numpy as jnp
 __all__ = [
     "mxu_block_sq", "lb_penalized", "dade_threshold",
     "stage1_tile", "stage2_slab", "stage2_need", "stage2_tile",
-    "merge_topk_tile", "dup_mask",
+    "merge_topk_tile",
 ]
 
 
@@ -175,42 +176,56 @@ def stage2_tile(q, c, eps, scale, rsq, active0, valid, *, block_d: int):
     return psum, passed, d32, slabs
 
 
-def merge_topk_tile(top_sq, top_ids, new_sq, new_ids, *, k: int):
-    """Merge a (BQ, BC) candidate tile into the running (BQ, K) top-K.
+def merge_topk_tile(top_sq, top_ids, exact_sq, ok, new_ids):
+    """Insert a (BQ, BC) tile's entrants into the running (BQ, K) top-K.
 
-    Portable K-step selection (min + one-hot extract) instead of
-    ``lax.top_k`` so the same code lowers in Mosaic and interpret mode.
-    The loop unrolls K times, which bounds K at 128 (the megakernel
-    wrappers enforce ``1 <= k/ef <= 128``).  ``new_sq`` must already be
-    inf for rows that must not enter (invalid, failed, duplicate).
-    Returns (top_sq, top_ids) sorted ascending.
+    ``top_sq`` ascending (inf slots carry id -1); ``exact_sq`` the tile's
+    distances, of which only ``ok`` rows may enter; ``new_ids`` (1, BC).
+    Each round takes every query row's least remaining ``(sq, column)``
+    pair — re-derived from the read-only tile as the least pair after the
+    previous round's, so the loop carries only (BQ, K)/(BQ, 1) state — and
+    inserts it after every window value ``<=`` it, if it beats the K-th and
+    its id is not already in the window the tile started from (overlapping
+    probe windows rescan rows; r never loosens, so a row that fell out can
+    never come back).  Rounds run while some row's next pair beats its K-th:
+    none when nothing can enter.  The result is the K least of
+    ``[top, entrants]`` by a stable sort, old entries first among equal
+    values.  Returns (top_sq, top_ids, rounds).
     """
-    all_sq = jnp.concatenate([top_sq, new_sq], axis=1)
-    all_ids = jnp.concatenate([top_ids, jnp.broadcast_to(new_ids, new_sq.shape)], axis=1)
-    iota = jax.lax.broadcasted_iota(jnp.int32, all_sq.shape, 1)
-    sq_cols, id_cols = [], []
-    for _ in range(k):
-        m = jnp.min(all_sq, axis=1, keepdims=True)  # (BQ, 1)
-        am = jnp.argmin(all_sq, axis=1).astype(jnp.int32)
-        onehot = iota == am[:, None]
-        sel = jnp.sum(jnp.where(onehot, all_ids, 0), axis=1, keepdims=True)
-        sel = jnp.where(jnp.isinf(m), jnp.int32(-1), sel)
-        sq_cols.append(m)
-        id_cols.append(sel)
-        all_sq = jnp.where(onehot, jnp.inf, all_sq)
-    return jnp.concatenate(sq_cols, axis=1), jnp.concatenate(id_cols, axis=1)
+    k = top_sq.shape[1]
+    bc = exact_sq.shape[1]
+    cand = jnp.where(ok, exact_sq, jnp.inf)
+    col = jax.lax.broadcasted_iota(jnp.int32, cand.shape, 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, top_sq.shape, 1)
+    old_ids = jnp.where(top_ids >= 0, top_ids, -2)  # -1 ids match nothing
 
+    def least(after):
+        rest = jnp.where(after, cand, jnp.inf)
+        m = jnp.min(rest, axis=1, keepdims=True)
+        c = jnp.min(jnp.where(after & (rest == m), col, bc), axis=1,
+                    keepdims=True)
+        return m, c
 
-def dup_mask(new_ids, top_ids, *, k: int):
-    """(BQ, BC) bool — candidate id already present in the running top-K.
+    def live(state):
+        t_sq, _, m, _, _ = state
+        return jnp.sum((m < t_sq[:, k - 1:k]).astype(jnp.int32)) > 0
 
-    Probed windows can overlap (offsets round down to tile boundaries and
-    adjacent buckets share tiles), so the same corpus row may be scanned
-    twice; without this mask it could occupy two top-K slots.  Checking
-    against the *current* top-K suffices: r never loosens, so a row that
-    fell out of the top-K can never re-enter.
-    """
-    dup = jnp.zeros(new_ids.shape, bool)
-    for j in range(k):
-        dup = dup | ((new_ids == top_ids[:, j:j + 1]) & (top_ids[:, j:j + 1] >= 0))
-    return dup
+    def insert(state):
+        t_sq, t_ids, m, c, rounds = state
+        cid = jnp.sum(jnp.where(col == c, new_ids, 0), axis=1, keepdims=True)
+        dup = jnp.sum((old_ids == cid).astype(jnp.int32), axis=1,
+                      keepdims=True) > 0
+        enter = (m < t_sq[:, k - 1:k]) & ~dup
+        pos = jnp.sum((t_sq <= m).astype(jnp.int32), axis=1, keepdims=True)
+        # jnp.roll's wrapped slot 0 is never read: slot 0 > pos cannot hold.
+        t_sq = jnp.where(~enter | (slot < pos), t_sq,
+                         jnp.where(slot == pos, m, jnp.roll(t_sq, 1, 1)))
+        t_ids = jnp.where(~enter | (slot < pos), t_ids,
+                          jnp.where(slot == pos, cid, jnp.roll(t_ids, 1, 1)))
+        m, c = least((cand > m) | ((cand == m) & (col > c)))
+        return t_sq, t_ids, m, c, rounds + 1
+
+    m, c = least(jnp.ones(cand.shape, bool))
+    top_sq, top_ids, _, _, rounds = jax.lax.while_loop(
+        live, insert, (top_sq, top_ids, m, c, jnp.int32(0)))
+    return top_sq, top_ids, rounds

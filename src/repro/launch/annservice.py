@@ -327,10 +327,11 @@ def build_search_step(svc: ServiceConfig, mesh, *, two_phase: bool = True,
     (``ops.flat_tile_shape``).
 
     ``with_stats`` (fused route only) appends a third output: a replicated
-    (6,) f32 vector of the megakernel's scan counters summed over shards
+    (7,) f32 vector of the megakernel's scan counters summed over shards
     and queries (``repro.kernels.ivf_scan.STATS_COLS`` order) — the serving
     driver turns columns 4-5 into the fetched-vs-skipped stage-2 byte
-    report per wave.
+    report per wave and feeds column 6, the merge's rounds, to
+    ``ivf.fused.merge_rounds``.
     """
     from repro.kernels.ops import auto_interpret, flat_tile_shape
 
@@ -609,9 +610,9 @@ def build_search_step(svc: ServiceConfig, mesh, *, two_phase: bool = True,
         dists = jnp.sqrt(jnp.maximum(top_sq, 0.0))
         if not with_stats:
             return dists, top_ids
-        # Tile-level fetch counters (cols 4-5) are broadcast to every query
-        # row of a tile; stride-sample the first row per tile (lossless)
-        # before summing, then reduce across shards.
+        # Tile-level counters (cols 4-6) are broadcast to every query row
+        # of a tile; stride-sample the first row per tile (lossless) before
+        # summing, then reduce across shards.
         scan = jnp.concatenate([
             jnp.sum(stats[:, :4], axis=0),
             jnp.sum(stats[::block_q, 4:], axis=0),

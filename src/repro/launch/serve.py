@@ -226,6 +226,7 @@ def main(argv: list[str] | None = None) -> dict:
     from repro.configs.dade_ivf import ServiceConfig
     from repro.core import build_estimator, exact_knn
     from repro.data.pipeline import synthetic_queries, synthetic_vectors
+    from repro.kernels.ivf_scan import STATS_COLS
     from repro.kernels.ops import auto_block_q, block_table, kernel_spec
     from repro.launch.annservice import build_search_step, search_input_specs
     from repro.launch.compile_cache import enable_compile_cache
@@ -1247,7 +1248,7 @@ def main(argv: list[str] | None = None) -> dict:
 
     # Variable-size requests flow through the dynamic batcher; the compiled
     # step always sees the fixed (query_batch, D) shape.
-    scan_totals = np.zeros((6,), np.float64)
+    scan_totals = np.zeros((len(STATS_COLS),), np.float64)
 
     def fixed_step(batch_np):
         with current_tracer().span("engine.step", route="flat",
@@ -1323,6 +1324,7 @@ def main(argv: list[str] | None = None) -> dict:
         record_fused_serve_totals(
             reg,
             s1_tiles=float(s1_tiles), s2_slabs=float(s2_slabs),
+            merge_rounds=float(scan_totals[6]),
             s1_bytes=float(fetched_tile_bytes(
                 s1_tiles, block_c=block_c, dims=d_pad,
                 bytes_per_dim=1, id_bytes=ID_BYTES)),

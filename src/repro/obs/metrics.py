@@ -383,14 +383,16 @@ def record_drift(reg: MetricsRegistry, watchdog) -> None:
 
 
 def record_fused_serve_totals(reg: MetricsRegistry, *, s1_tiles: float,
-                              s2_slabs: float, s1_bytes: float,
-                              s2_bytes: float, sem_bytes: float) -> None:
-    """Feed the flat fused serving route's scan-counter totals (the (6,)
+                              s2_slabs: float, merge_rounds: float,
+                              s1_bytes: float, s2_bytes: float,
+                              sem_bytes: float) -> None:
+    """Feed the flat fused serving route's scan-counter totals (the (7,)
     ``STATS_COLS`` vector the shard_mapped step psums) into the registry —
     the serve driver computes the byte figures with the same
     ``accounting.py`` helpers it prints."""
     reg.counter("ivf.fused.s1_tiles_fetched").add(s1_tiles)
     reg.counter("ivf.fused.s2_slabs_fetched").add(s2_slabs)
+    reg.counter("ivf.fused.merge_rounds").add(merge_rounds)
     reg.counter("dco.semantic.bytes").add(sem_bytes)
     reg.counter("dco.fetched.bytes").add(s1_bytes + s2_bytes)
 

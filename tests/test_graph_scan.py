@@ -129,6 +129,9 @@ def test_graph_kernel_matches_ref(qn, d, block_q, ef, steps, block_d):
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(st1), np.asarray(st2), rtol=1e-6)
     assert float(np.asarray(st1)[:, 0].sum()) > 0  # real two-stage work
+    # the shared merge's rounds, column 6, count alike in kernel and oracle
+    assert np.array_equal(np.asarray(st1)[:, 6], np.asarray(st2)[:, 6])
+    assert float(np.asarray(st1)[:, 6].sum()) > 0
     # the device-owned visited bitmap: kernel == oracle, and its bits are
     # exactly the real offsets each tile expanded
     assert np.array_equal(np.asarray(vis1), np.asarray(vis2))

@@ -24,7 +24,9 @@ from repro.kernels.ops import (
     block_table, build_window_offsets, ivf_cap_tiles, ivf_scan_kernel,
     min_block_q, on_tpu,
 )
+from repro.kernels.ivf_scan import STATS_COLS
 from repro.kernels.ref import ivf_scan_ref
+from repro.kernels.tiles import merge_topk_tile
 from repro.quant.scalar import (
     block_err_cum,
     fit_block_scales,
@@ -106,6 +108,7 @@ def test_block_scale_lower_bound_property(seed, n, d):
     (5, 40, 4, 32, 8, 2),     # nothing 128-aligned
     (16, 96, 8, 128, 32, 4),  # D padded 96 -> 96 (3 blocks), cap window
     (16, 64, 16, 512, 16, 2),  # the flat route's wide tile: one query tile
+    (64, 256, 64, 2048, 128, 2),  # the flat route's serving tile shape
 ])
 def test_fused_kernel_matches_ref(qn, d, block_q, block_c, block_d, n_probe):
     rng = np.random.default_rng(qn + d)
@@ -154,6 +157,11 @@ def test_fused_kernel_matches_ref(qn, d, block_q, block_c, block_d, n_probe):
     np.testing.assert_allclose(np.asarray(st1), np.asarray(st2), rtol=1e-6)
     # the screen actually did two-stage work
     assert float(np.asarray(st1)[:, 0].sum()) > 0
+    # the merge's rounds: one count per tile, equal to the oracle's
+    st1, st2 = np.asarray(st1), np.asarray(st2)
+    assert st1.shape[1] == len(STATS_COLS) and STATS_COLS[6] == "merge_rounds"
+    assert np.array_equal(st1[:, 6], st2[:, 6])
+    assert float(st1[:, 6].sum()) > 0
 
 
 def test_fused_kernel_compiled_matches_ref():
@@ -197,6 +205,85 @@ def test_compiled_block_q_guard(fused_idx, queries):
     with pytest.raises(ValueError, match="lane-aligned"):
         search_ivf_fused(fused_idx, jnp.asarray(queries), k=10, n_probe=4,
                          block_q=32, interpret=False)
+
+
+# ---- the top-K merge against a stable sort --------------------------------
+
+def _merge_reference(top_sq, top_ids, sq, ok, ids):
+    """NumPy reference of ``tiles.merge_topk_tile``: the K least of a stable
+    sort of [old top, the tile's entrants], entrants whose id is already in
+    the old top removed, inf slots id -1; and the rounds the insertion
+    merge runs, the most candidates any row takes out in (sq, column) order
+    before its next one no longer beats its K-th."""
+    k = top_sq.shape[1]
+    out_sq, out_ids, taken = [], [], []
+    for r in range(top_sq.shape[0]):
+        new = np.where(ok[r], sq[r], np.inf)
+        dup = np.isin(ids[0], top_ids[r][top_ids[r] >= 0])
+        all_sq = np.concatenate([top_sq[r], np.where(dup, np.inf, new)])
+        all_ids = np.concatenate([top_ids[r], ids[0]])
+        order = np.argsort(all_sq, kind="stable")[:k]
+        out_sq.append(all_sq[order])
+        out_ids.append(np.where(np.isinf(all_sq[order]), -1, all_ids[order]))
+        window, n = list(top_sq[r]), 0
+        for c in np.lexsort((np.arange(new.size), new)):
+            if not new[c] < window[-1]:
+                break
+            n += 1
+            if not dup[c]:
+                window.insert(int(np.searchsorted(window, new[c], "right")),
+                              new[c])
+                window.pop()
+        taken.append(n)
+    return np.stack(out_sq), np.stack(out_ids), max(taken)
+
+
+@pytest.mark.parametrize("k", [1, 10, 128])
+@pytest.mark.parametrize("width", [128, 2048])
+def test_merge_topk_tile_matches_stable_sort(k, width):
+    """Bit parity with the stable-sort reference over heavy ties, empty,
+    partial and full tops, rows that pass nothing or everything, invalid
+    (-1) rows, and rescanned rows whose id is already in the top at the
+    same distance; the merge runs exactly the rounds the reference counts
+    (one query row: that row's candidates taken out)."""
+    rng = np.random.default_rng(k * 7919 + width)
+    merge = jax.jit(merge_topk_tile)
+    cases = 0
+    for bq in (1, 8):
+        for fill in ("empty", "partial", "full"):
+            for levels in (4, 1000):
+                ids = rng.permutation(4 * width)[:width].astype(np.int32)[None]
+                ids[0, rng.random(width) < 0.1] = -1
+                sq = rng.integers(0, levels, (bq, width)).astype(np.float32)
+                p_ok = rng.choice([0.0, 0.01, 0.3, 1.0], bq)
+                ok = (rng.random((bq, width)) < p_ok[:, None]) & (ids >= 0)
+                top_sq = np.full((bq, k), np.inf, np.float32)
+                top_ids = np.full((bq, k), -1, np.int32)
+                for r in range(bq):
+                    n = {"empty": 0, "full": k}.get(fill, int(rng.integers(1, k + 1)))
+                    top_sq[r, :n] = np.sort(
+                        rng.integers(0, levels, n).astype(np.float32))
+                    # old entries: some are rows of this tile (a rescan),
+                    # the rest rows the tile does not hold
+                    pick = rng.permutation(np.concatenate([
+                        ids[0][ids[0] >= 0][:max(n // 2, 1)],
+                        np.arange(4 * width, 4 * width + k)]))[:n]
+                    top_ids[r, :n] = pick
+                    for j in range(n):
+                        col = np.flatnonzero(ids[0] == pick[j])
+                        if col.size and rng.random() < 0.8:
+                            sq[r, col] = top_sq[r, j]
+                            ok[r, col] = True
+                want_sq, want_ids, want_rounds = _merge_reference(
+                    top_sq, top_ids, sq, ok, ids)
+                got_sq, got_ids, rounds = merge(
+                    jnp.asarray(top_sq), jnp.asarray(top_ids),
+                    jnp.asarray(sq), jnp.asarray(ok), jnp.asarray(ids))
+                assert np.array_equal(np.asarray(got_sq), want_sq)
+                assert np.array_equal(np.asarray(got_ids), want_ids)
+                assert int(rounds) == want_rounds
+                cases += want_rounds > 0
+    assert cases > 0
 
 
 # ---- demand-paged fetch elision: soundness + bit-identity property ---------
@@ -576,4 +663,7 @@ def test_flat_fused_step_wide_tile_matches_narrow(monkeypatch):
     # two 8-row tiles over 128-row tiles
     n_tiles = svc.corpus_per_device
     assert st_w[5] == n_tiles // 2048 and st_n[5] == 2 * n_tiles // 128
+    # the step returns every STATS_COLS column; both tile shapes merged
+    assert st_w.shape == st_n.shape == (len(STATS_COLS),)
+    assert st_w[6] > 0 and st_n[6] > 0
     assert st_w[2] == st_n[2] == svc.query_batch * svc.corpus_per_device

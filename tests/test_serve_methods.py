@@ -53,6 +53,7 @@ def test_serve_adsampling_fused_reports_method_and_ledger(tmp_path):
     assert m["dco.method.adsampling"]["value"] == queries
     assert m["dco.fetched.bytes"]["value"] > 0
     assert m["dco.semantic.bytes"]["value"] > 0
+    assert m["ivf.fused.merge_rounds"]["value"] > 0
 
     # the stdlib schema gate (CI's check) must accept the same snapshot
     r = subprocess.run(
